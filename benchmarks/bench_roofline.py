@@ -2,46 +2,34 @@
 
 Reads ``experiments/dryrun_{single,multi}.json`` written by
 ``python -m repro.launch.dryrun --all [--multipod] --out experiments`` and
-emits one CSV row per pair.  If the artifacts are missing (fresh clone), a
-reduced-scale dry-run is executed inline via subprocess so the benchmark is
-self-contained.
+emits one CSV row per pair.  A missing artifact is a failure that names the
+command producing it: the dry-run compiles for 512 placeholder devices in a
+process of its own, and this bench runs inside the harness's JAX process,
+which must not start another.
 """
 import json
 import os
-import subprocess
-import sys
 
 from benchmarks.common import csv_row
 
 ART = os.path.join(os.path.dirname(__file__), "..", "experiments")
 
 
-def _ensure(tag: str):
+def _artifact(tag: str) -> str:
     path = os.path.join(ART, f"dryrun_{tag}.json")
-    if os.path.exists(path):
-        return path
-    # self-contained fallback: run two representative pairs only (compile
-    # cost of the full 40-pair sweep belongs to the dryrun CLI, not here)
-    os.makedirs(ART, exist_ok=True)
-    cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", "qwen3-8b",
-           "--shape", "train_4k", "--out", ART]
-    if tag == "multi":
-        cmd.append("--multipod")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    subprocess.run(cmd + ["--all"][:0], env=env, check=False,
-                   capture_output=True)
-    return path if os.path.exists(path) else None
+    if not os.path.exists(path):
+        cmd = ("PYTHONPATH=src python -m repro.launch.dryrun --all "
+               "--out experiments" + (" --multipod" if tag == "multi" else ""))
+        raise FileNotFoundError(
+            f"dry-run artifact {path} is missing; produce it with: {cmd}")
+    return path
 
 
 def run(paper_scale: bool = False):
     rows = []
     for tag in ("single", "multi"):
-        path = _ensure(tag)
-        if path is None:
-            rows.append(csv_row(f"roofline/{tag}", 0.0, "missing_artifacts"))
-            continue
-        data = json.load(open(path))
+        with open(_artifact(tag)) as f:
+            data = json.load(f)
         for r in data:
             if "error" in r:
                 rows.append(csv_row(

@@ -137,6 +137,8 @@ def main() -> int:
     if args.compare:
         return compare(*args.compare)
     chosen = args.only.split(",") if args.only else list(MODULES)
+    from repro.utils.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
 
     trace_root = None
     if args.profile:

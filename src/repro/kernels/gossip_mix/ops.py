@@ -6,6 +6,9 @@ lane-aligned tile and N up to the sublane boundary (padding P with identity
 rows so padded workers mix with nobody).  ``masked_gossip_mix`` additionally
 folds the per-event learning-rate/gradient mask into a second resident matrix
 Q = diag(η·mask)·P so the scan body's whole event update is one kernel call.
+Every wrapper sizes the kernel's scoped VMEM from N and the tile width
+(``_vmem_limit``): the resident (N, N) matrices outgrow the TPU's default
+limit near N=1024.
 """
 from __future__ import annotations
 
@@ -20,6 +23,24 @@ from repro.kernels.gossip_mix.kernel import (gossip_mix_batched_pallas,
                                              masked_gossip_pallas)
 
 _SUBLANE = 8
+# v5e's default scoped-VMEM limit, and the most a kernel here may ask for of
+# the 128 MiB of VMEM a v5e TensorCore has.
+_VMEM_DEFAULT = 16 << 20
+_VMEM_CAP = 100 << 20
+
+
+def _vmem_limit(Np: int, block_d: int, itemsize: int, n_square: int,
+                n_tiles: int) -> int:
+    """Scoped-VMEM bytes for a gossip kernel over (Np, block_d) tiles.
+
+    Pallas double-buffers every block: ``n_square`` resident (Np, Np)
+    matrices and ``n_tiles`` (Np, block_d) input/output tiles, plus one f32
+    matmul result per resident matrix.  A quarter of headroom on top; never
+    below the default limit, never above the cap.
+    """
+    need = (2 * itemsize * (n_square * Np * Np + n_tiles * Np * block_d)
+            + 4 * n_square * Np * block_d)
+    return min(max(_VMEM_DEFAULT, need + need // 4), _VMEM_CAP)
 
 
 def _on_tpu() -> bool:
@@ -54,8 +75,11 @@ def gossip_mix(W: jax.Array, P: jax.Array, *, block_d: int = 512,
         flat = jnp.pad(flat, ((0, Np - N), (0, 0)))
         P = _pad_P_identity(P, N, Np)
     with jax.named_scope("gossip_mix"):
-        out = gossip_mix_pallas(flat, P.astype(flat.dtype), block_d=block_d,
-                                interpret=interpret)
+        out = gossip_mix_pallas(
+            flat, P.astype(flat.dtype), block_d=block_d,
+            vmem_limit_bytes=_vmem_limit(Np, block_d, flat.dtype.itemsize,
+                                         n_square=1, n_tiles=2),
+            interpret=interpret)
     return out[:N, :D].reshape(orig_shape)
 
 
@@ -88,8 +112,11 @@ def masked_gossip_mix(W: jax.Array, G: jax.Array, P: jax.Array,
     P = P.astype(flat_w.dtype)
     Q = scaled_mask.astype(flat_w.dtype)[:, None] * P
     with jax.named_scope("masked_gossip_mix"):
-        out = masked_gossip_pallas(flat_w, flat_g, P, Q, block_d=block_d,
-                                   interpret=interpret)
+        out = masked_gossip_pallas(
+            flat_w, flat_g, P, Q, block_d=block_d,
+            vmem_limit_bytes=_vmem_limit(Np, block_d, flat_w.dtype.itemsize,
+                                         n_square=2, n_tiles=3),
+            interpret=interpret)
     return out[:N, :D].reshape(orig_shape)
 
 
@@ -112,6 +139,9 @@ def gossip_mix_batched(W: jax.Array, P: jax.Array, *, block_d: int = 512,
         P = jnp.pad(P, ((0, 0), (0, Np - N), (0, Np - N)))
         P = P.at[:, jnp.arange(N, Np), jnp.arange(N, Np)].set(1.0)
     with jax.named_scope("gossip_mix_batched"):
-        out = gossip_mix_batched_pallas(flat, P.astype(flat.dtype),
-                                        block_d=block_d, interpret=interpret)
+        out = gossip_mix_batched_pallas(
+            flat, P.astype(flat.dtype), block_d=block_d,
+            vmem_limit_bytes=_vmem_limit(Np, block_d, flat.dtype.itemsize,
+                                         n_square=1, n_tiles=2),
+            interpret=interpret)
     return out[:, :N, :D].reshape(orig_shape)

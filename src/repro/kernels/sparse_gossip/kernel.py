@@ -42,10 +42,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _sparse_gossip_kernel(workers_ref, p_ref, q_ref, w_ref, g_ref, o_ref):
+def _sparse_gossip_kernel(workers_ref, pt_ref, qt_ref, w_ref, g_ref, o_ref):
     # workers_ref: (A,) scalar-prefetch (consumed by the index maps);
-    # p_ref/q_ref: (A, A) resident; w_ref: (1, Dt) gathered row W[workers[a]];
-    # g_ref: (1, Dt) compact gradient row a; o_ref: (A, Dt) resident tile.
+    # pt_ref/qt_ref: (A, A) resident P_subᵀ/Q_subᵀ; w_ref: (1, Dt) gathered
+    # row W[workers[a]]; g_ref: (1, Dt) compact gradient row a; o_ref:
+    # (A, Dt) resident tile.
     del workers_ref
     a = pl.program_id(1)
 
@@ -53,8 +54,16 @@ def _sparse_gossip_kernel(workers_ref, p_ref, q_ref, w_ref, g_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    contrib = (p_ref[a, :][:, None] * w_ref[...]
-               - q_ref[a, :][:, None] * g_ref[...])
+    # Row a of P_sub as an (A, 1) column: column a of P_subᵀ, picked by a
+    # lane mask (a one-term sum, so exact).  A dynamic row index into the
+    # ref would need a sublane offset the compiler cannot prove aligned.
+    lane = jax.lax.broadcasted_iota(jnp.int32, pt_ref.shape, 1)
+
+    def column(ref):
+        return jnp.sum(jnp.where(lane == a, ref[...], 0), axis=1,
+                       keepdims=True)
+
+    contrib = column(pt_ref) * w_ref[...] - column(qt_ref) * g_ref[...]
     o_ref[...] += contrib.astype(o_ref.dtype)
 
 
@@ -75,15 +84,20 @@ def sparse_gossip_pallas(W: jax.Array, G: jax.Array, P_sub: jax.Array,
         P_sub.shape, Q_sub.shape)
     assert D % block_d == 0, (D, block_d)
     grid = (D // block_d, A)
+    # Rows are gathered through (rows, 1, D) views whose row axis is squeezed
+    # out of the block: a TPU block must tile its last two dims by (8, 128)
+    # or span them, and a (1, block_d) window of the (N, D) stack does
+    # neither, while a (1, block_d) window of (N, 1, D) spans its dim 1.
+    row_block = (pl.squeezed, 1, block_d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((A, A), lambda d, a, workers: (0, 0)),  # P resident
-            pl.BlockSpec((A, A), lambda d, a, workers: (0, 0)),  # Q resident
+            pl.BlockSpec((A, A), lambda d, a, workers: (0, 0)),  # Pᵀ resident
+            pl.BlockSpec((A, A), lambda d, a, workers: (0, 0)),  # Qᵀ resident
             # the gather: row a of the active set comes from W[workers[a]]
-            pl.BlockSpec((1, block_d), lambda d, a, workers: (workers[a], d)),
-            pl.BlockSpec((1, block_d), lambda d, a, workers: (a, d)),
+            pl.BlockSpec(row_block, lambda d, a, workers: (workers[a], 0, d)),
+            pl.BlockSpec(row_block, lambda d, a, workers: (a, 0, d)),
         ],
         out_specs=pl.BlockSpec((A, block_d), lambda d, a, workers: (0, d)),
     )
@@ -92,26 +106,34 @@ def sparse_gossip_pallas(W: jax.Array, G: jax.Array, P_sub: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((A, D), W.dtype),
         interpret=interpret,
-    )(workers, P_sub, Q_sub, W, G)
+    )(workers, P_sub.T, Q_sub.T, W.reshape(N, 1, D), G.reshape(A, 1, D))
 
 
-def _scatter_rows_kernel(workers_ref, rows_ref, x_ref, o_ref):
-    # workers_ref: (A,) scalar-prefetch; x_ref / o_ref: the same (1, Dt)
-    # window of the aliased carry at row max(workers[a], 0); rows_ref: the
-    # compact row of lane a for valid lanes, of *worker 0's lane* for
-    # padded lanes (see the index map).  A valid lane replaces its window
-    # with its compact row.  A padded lane (workers[a] < 0, clamped to
-    # row 0) must write row 0's *final* content back: that is the owning
+def _scatter_rows_kernel(workers_ref, owner0_ref, rows_ref, x_ref, o_ref):
+    # workers_ref: (A,) scalar-prefetch; owner0_ref: (1,) scalar-prefetch,
+    # the lane that carries worker 0 or -1 when none does; x_ref / o_ref:
+    # the same (1, Dt) window of the aliased carry at row max(workers[a], 0);
+    # rows_ref: the compact row of lane a for valid lanes, of *worker 0's
+    # lane* for padded lanes (see the index map).  A valid lane replaces its
+    # window with its compact row.  A padded lane (workers[a] < 0, clamped
+    # to row 0) must write row 0's *final* content back: that is the owning
     # lane's compact row when some valid lane carries worker 0 — wherever
     # that lane sits (merged block-diagonal rows interleave pads, so it
     # need not be lane 0) — else the gathered window.  Deciding from the
     # workers array rather than re-reading the carry keeps the kernel
     # correct whether the x gather observes the aliased buffer's updates
-    # (TPU read-through) or a stale pre-kernel copy (interpret mode).
+    # (TPU read-through) or a stale pre-kernel copy (interpret mode).  Both
+    # decisions read scalars only: TPU kernels load SMEM one scalar at a time.
     a = pl.program_id(1)
-    keep_rows = (workers_ref[a] >= 0) | jnp.any(workers_ref[...] == 0)
-    o_ref[...] = jnp.where(keep_rows, rows_ref[...],
-                           x_ref[...]).astype(o_ref.dtype)
+    keep_rows = (workers_ref[a] >= 0) | (owner0_ref[0] >= 0)
+
+    @pl.when(keep_rows)
+    def _write_rows():
+        o_ref[...] = rows_ref[...].astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(keep_rows))
+    def _write_back():
+        o_ref[...] = x_ref[...].astype(o_ref.dtype)
 
 
 def scatter_rows_pallas(X: jax.Array, rows: jax.Array, workers: jax.Array, *,
@@ -143,31 +165,41 @@ def scatter_rows_pallas(X: jax.Array, rows: jax.Array, workers: jax.Array, *,
     A = workers.shape[0]
     assert rows.shape == (A, D), (rows.shape, (A, D))
     assert D % block_d == 0, (D, block_d)
+    # worker 0's lane (or -1), worked out here so the kernel and its index
+    # maps read one scalar instead of a vector reduction over the prefetch
+    is0 = workers == 0
+    owner0 = jnp.where(jnp.any(is0), jnp.argmax(is0), -1).astype(
+        jnp.int32).reshape(1)
+    # (rows, 1, D) views with a squeezed row axis: see sparse_gossip_pallas
+    X3 = X.reshape(N, 1, D)
+    row_block = (pl.squeezed, 1, block_d)
     grid = (D // block_d, A)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
             # padded lanes read worker 0's owning lane (the row-0 writeback
-            # candidate; argmax is 0 when no lane carries worker 0, and the
+            # candidate; lane 0 when no lane carries worker 0, and the
             # kernel then keeps the gathered window instead)
-            pl.BlockSpec((1, block_d),
-                         lambda d, a, workers: (jnp.where(
+            pl.BlockSpec(row_block,
+                         lambda d, a, workers, owner0: (jnp.where(
                              workers[a] >= 0, a,
-                             jnp.argmax(workers[...] == 0)
-                             .astype(jnp.int32)),
-                             d)),
-            pl.BlockSpec((1, block_d),
-                         lambda d, a, workers: (jnp.maximum(workers[a], 0), d)),
+                             jnp.maximum(owner0[0], 0)), 0, d)),
+            pl.BlockSpec(row_block,
+                         lambda d, a, workers, owner0: (
+                             jnp.maximum(workers[a], 0), 0, d)),
         ],
         out_specs=pl.BlockSpec(
-            (1, block_d), lambda d, a, workers: (jnp.maximum(workers[a], 0), d)),
+            row_block,
+            lambda d, a, workers, owner0: (jnp.maximum(workers[a], 0), 0, d)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _scatter_rows_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, D), X.dtype),
-        # operand indices count the scalar-prefetch arg: (workers, rows, X)
-        input_output_aliases={2: 0},
+        out_shape=jax.ShapeDtypeStruct(X3.shape, X3.dtype),
+        # operand indices count the scalar-prefetch args:
+        # (workers, owner0, rows, X)
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(workers, rows, X)
+    )(workers, owner0, rows.reshape(A, 1, D), X3)
+    return out.reshape(N, D)

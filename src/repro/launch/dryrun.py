@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × input shape × mesh).
 
 Proves the distribution config is coherent without hardware: 512 placeholder
@@ -15,6 +12,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -199,6 +197,9 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
 
 
 def main():
+    # 512 placeholder host devices; read when the backend first starts, so
+    # this must run before anything touches a device
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")
@@ -236,4 +237,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

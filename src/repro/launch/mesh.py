@@ -14,15 +14,13 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.utils.compat import auto_axis_types, make_mesh, mesh_from_devices
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,8 +65,7 @@ def hierarchical_view(mesh: Mesh, workers: int, fsdp: int) -> Tuple[Mesh, TrainA
     if fsdp == 1:
         new = new.squeeze(axis=-2)
         new_names = tuple(n for n in new_names if n != "fsdp")
-    view = mesh_from_devices(new, new_names,
-                             axis_types=auto_axis_types(len(new_names)))
+    view = Mesh(new, new_names, axis_types=(AxisType.Auto,) * len(new_names))
     axes = TrainAxes(pod="pod" if multi_pod else None, worker="worker",
                      fsdp="fsdp" if fsdp > 1 else None, model="model")
     return view, axes

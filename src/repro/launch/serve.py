@@ -110,4 +110,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -26,7 +26,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import TrainAxes
-from repro.utils.compat import shard_map
 from repro.models.transformer import decode_step as _decode
 from repro.models.transformer import init_model, lm_loss
 from repro.models.transformer import prefill as _prefill
@@ -124,7 +123,7 @@ def build_train_step(cfg: ModelConfig, n_workers: int, axes: TrainAxes,
         l, g = jax.value_and_grad(worker_loss)(params, tokens, prefix)
         return l, g
 
-    gossip_sm = shard_map(
+    gossip_sm = jax.shard_map(
         lambda W, wt: _tree_gossip(W, axes, w_per_pod, wt),
         mesh=mesh, in_specs=(param_specs, P()), out_specs=param_specs,
         check_vma=False)

@@ -16,12 +16,15 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.utils.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
-def main(argv=None):
+def run_training(argv=None):
+    """Parse ``argv`` as the CLI does and train.
+
+    Returns ``(W, mesh, losses)``: the worker-stacked parameters as the
+    last step left them (sharded over ``mesh``) and the per-step mean loss.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--steps", type=int, default=20)
@@ -52,8 +55,8 @@ def main(argv=None):
         n_dev = jax.device_count()
         model_par = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
         data_par = max(1, n_dev // model_par)
-        base = make_mesh((data_par, model_par), ("data", "model"),
-                         axis_types=auto_axis_types(2))
+        base = jax.make_mesh((data_par, model_par), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         workers = args.workers or data_par
         fsdp = data_par // workers
         mesh, axes = hierarchical_view(base, workers, max(1, fsdp))
@@ -93,6 +96,7 @@ def main(argv=None):
         n_workers=n_workers))
     rng = np.random.default_rng(0)
 
+    losses = []
     with mesh:
         W = jax.jit(params_init, out_shardings=ns(pspecs))(jax.random.PRNGKey(0))
         ckpt = None
@@ -119,13 +123,22 @@ def main(argv=None):
             t0 = time.time()
             W, loss = jitted(W, batch, jnp.float32(args.eta), gw)
             loss = float(loss)
+            losses.append(loss)
             print(f"step {k:4d} loss {loss:.4f}  ({time.time()-t0:.2f}s)")
             if ckpt and args.ckpt_every and (k + 1) % args.ckpt_every == 0:
                 ckpt.save(k + 1, jax.device_get(W),
                           extra={"stream": {"cursor": stream.state_dict()["cursor"].tolist()}})
+    return W, mesh, losses
+
+
+def main(argv=None):
+    """CLI entry: exit status 1 when any step's loss is not finite."""
+    _, _, losses = run_training(argv)
     print("done")
-    return 0
+    return 0 if np.all(np.isfinite(losses)) else 1
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

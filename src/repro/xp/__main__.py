@@ -86,4 +86,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

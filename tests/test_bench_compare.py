@@ -130,3 +130,13 @@ class TestCompareGate:
     def test_recorded_artifact_self_compare(self):
         assert compare("BENCH_event_stream.json",
                        "BENCH_event_stream.json") == 0
+
+
+class TestRooflineArtifacts:
+    def test_missing_artifact_fails_naming_the_command(self, tmp_path,
+                                                       monkeypatch):
+        import benchmarks.bench_roofline as roofline
+        monkeypatch.setattr(roofline, "ART", str(tmp_path))
+        with pytest.raises(FileNotFoundError,
+                           match="python -m repro.launch.dryrun --all"):
+            roofline.run()

@@ -17,7 +17,9 @@ def run_py(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    # CPU only: a child that reached for the TPU library would contend with
+    # the test worker that may hold it
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -78,9 +80,9 @@ class TestMeshViews:
         out = run_py("""
             import jax
             from repro.launch.mesh import hierarchical_view
-            from repro.utils.compat import auto_axis_types, make_mesh
-            base = make_mesh((4, 2), ("data", "model"),
-                             axis_types=auto_axis_types(2))
+            from jax.sharding import AxisType
+            base = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
             v, axes = hierarchical_view(base, 2, 2)
             print(v.axis_names, v.shape["worker"], v.shape["fsdp"])
             v1, axes1 = hierarchical_view(base, 4, 1)
@@ -112,15 +114,15 @@ class TestGossipEquivalence:
             from repro.launch.mesh import TrainAxes
             from repro.launch.steps import _tree_gossip, default_gossip_weights
             from repro.core.consensus import metropolis_matrix
-            from repro.utils.compat import auto_axis_types, make_mesh, shard_map
+            from jax.sharding import AxisType
 
             n = 4
-            mesh = make_mesh((n,), ("worker",), axis_types=auto_axis_types(1))
+            mesh = jax.make_mesh((n,), ("worker",), axis_types=(AxisType.Auto,))
             axes = TrainAxes(pod=None, worker="worker", fsdp=None, model="model")
             W = {"w": jnp.arange(n * 6, dtype=jnp.float32).reshape(n, 6)}
             spec = {"w": P("worker", None)}
             gw = default_gossip_weights(n, False)
-            f = shard_map(lambda W: _tree_gossip(W, axes, n, gw),
+            f = jax.shard_map(lambda W: _tree_gossip(W, axes, n, gw),
                           mesh=mesh, in_specs=(spec,), out_specs=spec)
             out = f(W)
             Pm = metropolis_matrix(n, [(i, (i + 1) % n) for i in range(n)])
@@ -137,14 +139,14 @@ class TestGossipEquivalence:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.launch.mesh import TrainAxes
             from repro.launch.steps import _tree_gossip, default_gossip_weights
-            from repro.utils.compat import auto_axis_types, make_mesh, shard_map
-            mesh = make_mesh((2, 2), ("pod", "worker"),
-                             axis_types=auto_axis_types(2))
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh((2, 2), ("pod", "worker"),
+                             axis_types=(AxisType.Auto,) * 2)
             axes = TrainAxes(pod="pod", worker="worker", fsdp=None, model="model")
             W = {"w": jax.random.normal(jax.random.PRNGKey(0), (4, 5))}
             spec = {"w": P(("pod", "worker"), None)}
             gw = default_gossip_weights(2, True)
-            f = shard_map(lambda W: _tree_gossip(W, axes, 2, gw),
+            f = jax.shard_map(lambda W: _tree_gossip(W, axes, 2, gw),
                           mesh=mesh, in_specs=(spec,), out_specs=spec)
             out = f(W)
             print("MEAN_ERR",
@@ -165,10 +167,10 @@ class TestDryRunSmall:
             from repro.launch import sharding as S, shapes as SH, steps as ST
             from repro.launch.mesh import hierarchical_view
             from repro.models.transformer import init_model
-            from repro.utils.compat import auto_axis_types, make_mesh
+            from jax.sharding import AxisType
 
-            base = make_mesh((4, 2), ("data", "model"),
-                             axis_types=auto_axis_types(2))
+            base = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
             view, axes = hierarchical_view(base, 2, 2)
             cfg = get_config("qwen3-8b").reduced()
             nw = 2
@@ -222,9 +224,9 @@ class TestHloAnalysis:
             import jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.launch.hlo_analysis import analyze_hlo_text
-            from repro.utils.compat import auto_axis_types, make_mesh
-            mesh = make_mesh((2, 2), ("data", "model"),
-                             axis_types=auto_axis_types(2))
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
             def f(w, x):
                 def body(c, wi):
                     return jnp.tanh(c @ wi), ()
