@@ -79,6 +79,8 @@ class CheckConfig:
     )
     host_sync_scopes: Tuple[str, ...] = (
         r"^_dispatch_\w+$",
+        r"^_pack_\w+$",
+        r"^_launch_\w+$",
         r"^_run_scan$",
         r"^_run_sparse_stream$",
         r"^_run_fused$",

@@ -97,7 +97,7 @@ def masked_gossip_step(
     # worker state stays bf16 through the update instead of being promoted
     # by the f32 scalar (a scan carry must keep its dtype).
     scaled = eta * gm.astype(jnp.float32)
-    with jax.named_scope("masked_gossip_step"):
+    with jax.named_scope("mix"):
         if use_kernel:
             # Fused Pallas path: Pᵀ·(W − η·mask⊙G) in one kernel per leaf.
             from repro.kernels.gossip_mix import ops as gossip_ops
@@ -109,7 +109,8 @@ def masked_gossip_step(
             Wg = jax.tree.map(lambda w, g: w - expand(scaled, w) * g, W, grads)
             Wn = gossip_mix_dense(Wg, P, use_kernel=False)
         yn = jnp.einsum("n,nj->j", y, P.astype(y.dtype))
-        rm = restart_mask
+    rm = restart_mask
+    with jax.named_scope("s_update"):
         Sn = jax.tree.map(lambda s, w: jnp.where(expand(rm, w) > 0, w, s), S, Wn)
     return Wn, Sn, yn
 
@@ -189,7 +190,8 @@ def build_event_step(loss_fn: Callable, use_kernel: bool = False):
 
     @jax.jit
     def step(W, S, y, batches, P, grad_mask, restart_mask, eta):
-        grads = jax.vmap(grad_fn)(S, batches)
+        with jax.named_scope("grad"):
+            grads = jax.vmap(grad_fn)(S, batches)
         return masked_gossip_step(
             W, S, y, grads, P, grad_mask, restart_mask, eta, use_kernel=use_kernel)
 
@@ -214,6 +216,15 @@ def select_pool_batch(pools: Pytree, ptr: jax.Array) -> Pytree:
                 row, p, axis=0, keepdims=False))
         return pick(pool, idx)
     return jax.tree.map(sel, pools)
+
+
+def _dense_grads(grad_fn: Callable, S: Pytree, pools: Pytree,
+                 ptr: jax.Array) -> Pytree:
+    """Every worker's gradient at its snapshot on its current pool batch."""
+    with jax.named_scope("pool_select"):
+        batches = select_pool_batch(pools, ptr)
+    with jax.named_scope("grad"):
+        return jax.vmap(grad_fn)(S, batches)
 
 
 def masked_gossip_scan(
@@ -243,8 +254,7 @@ def masked_gossip_scan(
     def body(carry, ev):
         W, S, y, ptr = carry
         P, gm, rm, eta = ev
-        batches = select_pool_batch(pools, ptr)
-        grads = jax.vmap(grad_fn)(S, batches)
+        grads = _dense_grads(grad_fn, S, pools, ptr)
         W, S, y = masked_gossip_step(
             W, S, y, grads, P, gm, rm, eta, use_kernel=use_kernel)
         ptr = ptr + rm.astype(ptr.dtype)
@@ -276,22 +286,21 @@ def build_event_scan(loss_fn: Callable, use_kernel: bool = False,
 
     if not telemetry:
         @jax.jit
-        def block(W, S, y, ptr, pools, P_seq, grad_masks, restart_masks,
-                  etas):
+        def block_dense(W, S, y, ptr, pools, P_seq, grad_masks,
+                        restart_masks, etas):
             return masked_gossip_scan(
                 W, S, y, ptr, pools, grad_fn, P_seq, grad_masks,
                 restart_masks, etas, use_kernel=use_kernel)
 
-        return block
+        return block_dense
 
     @jax.jit
-    def block_tel(W, S, y, ptr, M, pools, P_seq, grad_masks, restart_masks,
-                  etas, ts, fin, ks, copies):
+    def block_dense_tel(W, S, y, ptr, M, pools, P_seq, grad_masks,
+                        restart_masks, etas, ts, fin, ks, copies):
         def body(carry, ev):
             W, S, y, ptr, M = carry
             P, gm, rm, eta, t, f, k, cp = ev
-            batches = select_pool_batch(pools, ptr)
-            grads = jax.vmap(grad_fn)(S, batches)
+            grads = _dense_grads(grad_fn, S, pools, ptr)
             W, S, y = masked_gossip_step(
                 W, S, y, grads, P, gm, rm, eta, use_kernel=use_kernel)
             ptr = ptr + rm.astype(ptr.dtype)
@@ -304,7 +313,7 @@ def build_event_scan(loss_fn: Callable, use_kernel: bool = False,
             (P_seq, grad_masks, restart_masks, etas, ts, fin, ks, copies))
         return carry
 
-    return block_tel
+    return block_dense_tel
 
 
 # ---------------------------------------------------------------------------
@@ -419,33 +428,39 @@ def sparse_event_update(
     with jax.named_scope("sparse_gather"):
         Sa = jax.tree.map(lambda s: s[gidx], S)
         ptra = ptr[gidx]
+        ya = y[gidx]
+        # the kernel gathers its W rows itself
+        Wa = None if use_kernel else jax.tree.map(lambda w: w[gidx], W)
+    with jax.named_scope("pool_select"):
         batches = select_pool_batch_at(pools, gidx, ptra)
+    with jax.named_scope("grad"):
         grads = jax.vmap(grad_fn)(Sa, batches)   # A gradient lanes, not n
     scaled = eta * (gm & valid).astype(jnp.float32)
     # -- compute: P_subᵀ·(W_a − η·mask⊙G) ----------------------------
-    if use_kernel:
-        from repro.kernels.sparse_gossip import ops as sparse_ops
-        Wn = jax.tree.map(
-            lambda w, g: sparse_ops.sparse_gossip_rows(
-                w, g, P_sub.astype(w.dtype), scaled.astype(w.dtype),
-                gidx),
-            W, grads)
-    else:
-        vf = valid.astype(jnp.float32)
-        Pm = P_sub * vf[:, None] * vf[None, :]
+    with jax.named_scope("mix"):
+        if use_kernel:
+            from repro.kernels.sparse_gossip import ops as sparse_ops
+            Wn = jax.tree.map(
+                lambda w, g: sparse_ops.sparse_gossip_rows(
+                    w, g, P_sub.astype(w.dtype), scaled.astype(w.dtype),
+                    gidx),
+                W, grads)
+        else:
+            vf = valid.astype(jnp.float32)
+            Pm = P_sub * vf[:, None] * vf[None, :]
 
-        def mix(w, g):
-            Wa = w[gidx]
-            stepped = (Wa - expand(scaled, Wa) * g).reshape(
-                Wa.shape[0], -1)
-            out = jnp.einsum("ad,ab->bd", stepped, Pm.astype(Wa.dtype),
-                             precision=jax.lax.Precision.HIGHEST)
-            return out.reshape(Wa.shape)
+            def mix(wa, g):
+                stepped = (wa - expand(scaled, wa) * g).reshape(
+                    wa.shape[0], -1)
+                out = jnp.einsum("ad,ab->bd", stepped, Pm.astype(wa.dtype),
+                                 precision=jax.lax.Precision.HIGHEST)
+                return out.reshape(wa.shape)
 
-        Wn = jax.tree.map(mix, W, grads)
-    ya = jnp.einsum("a,ab->b", y[gidx], P_sub.astype(y.dtype))
-    Sn = jax.tree.map(lambda s, w: jnp.where(expand(rm, w) > 0, w, s),
-                      Sa, Wn)
+            Wn = jax.tree.map(mix, Wa, grads)
+        ya = jnp.einsum("a,ab->b", ya, P_sub.astype(y.dtype))
+    with jax.named_scope("s_update"):
+        Sn = jax.tree.map(lambda s, w: jnp.where(expand(rm, w) > 0, w, s),
+                          Sa, Wn)
     # -- scatter -----------------------------------------------------
     with jax.named_scope("sparse_scatter"):
         if use_kernel:
@@ -504,17 +519,18 @@ def build_sparse_event_scan(loss_fn: Callable, use_kernel: bool = False,
 
     if not telemetry:
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-        def block(W, S, y, ptr, pools, workers_seq, P_sub_seq, grad_masks,
-                  restart_masks, etas):
+        def block_sparse(W, S, y, ptr, pools, workers_seq, P_sub_seq,
+                         grad_masks, restart_masks, etas):
             return sparse_gossip_scan(
                 W, S, y, ptr, pools, grad_fn, workers_seq, P_sub_seq,
                 grad_masks, restart_masks, etas, use_kernel=use_kernel)
 
-        return block
+        return block_sparse
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
-    def block_tel(W, S, y, ptr, M, pools, workers_seq, P_sub_seq,
-                  grad_masks, restart_masks, etas, ts, fin, ks, copies):
+    def block_sparse_tel(W, S, y, ptr, M, pools, workers_seq, P_sub_seq,
+                         grad_masks, restart_masks, etas, ts, fin, ks,
+                         copies):
         if etas.ndim == 1:
             etas_seq = jnp.broadcast_to(etas[:, None], grad_masks.shape)
         else:
@@ -542,4 +558,4 @@ def build_sparse_event_scan(loss_fn: Callable, use_kernel: bool = False,
              ts, fin, ks, copies))
         return carry
 
-    return block_tel
+    return block_sparse_tel

@@ -138,8 +138,8 @@ def build_fused_pair_scan(loss_fn: Callable, spec: Dict[str, object],
         return W, S, y, ptr, times, lock_free, i, p, t, t_ev
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 5, 6, 7))
-    def block(W, S, y, ptr, pools, times, lock_free, comm,
-              factors, picks, etas):
+    def block_fused(W, S, y, ptr, pools, times, lock_free, comm,
+                    factors, picks, etas):
         def body(carry, xs):
             W, S, y, ptr, times, lock_free, comm = carry
             factor, pick, eta = xs
@@ -160,4 +160,4 @@ def build_fused_pair_scan(loss_fn: Callable, spec: Dict[str, object],
         return jax.lax.scan(body, (W, S, y, ptr, times, lock_free, comm),
                             (factors, picks, etas))
 
-    return block
+    return block_fused

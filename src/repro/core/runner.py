@@ -141,6 +141,46 @@ class RunResult:
         return None
 
 
+@dataclasses.dataclass
+class RunCounters:
+    """The runner's work since the trainer was built, always on.
+
+    Summed once per chunk or per block with numpy sums, never per lane.
+    ``run()`` attaches each run's share (:meth:`since`) to its
+    ``runner:run`` profiler span as span metadata, so a profiler trace
+    carries the counts beside the spans.  ``per_event`` mode dispatches no
+    block programs (``blocks``/``rows`` stay 0); ``fused`` mode draws its
+    pairs on the device, so its ``active`` lanes stay uncounted.
+    """
+    events: int = 0       # events consumed
+    blocks: int = 0       # block programs dispatched
+    rows: int = 0         # scan rows dispatched, padding included
+    active: int = 0       # active lanes of the consumed events
+    grad: int = 0         # gradient lanes of the consumed events
+    restarts: int = 0     # restarted lanes of the consumed events
+
+    def add(self, **counts: int) -> None:
+        for k, v in counts.items():
+            setattr(self, k, getattr(self, k) + int(v))
+
+    def since(self, before: "RunCounters") -> Dict[str, int]:
+        return {f.name: getattr(self, f.name) - getattr(before, f.name)
+                for f in dataclasses.fields(self)}
+
+
+def _lane_counts(chunk) -> Dict[str, int]:
+    """Active, gradient and restarted lanes of a packed sparse chunk."""
+    parts = (chunk.batches if isinstance(chunk, BucketedSparseEventBatch)
+             else (chunk,))
+    parts = [b for b in parts if b is not None]
+    return {"active": sum(int(b.n_workers.sum()) for b in parts),
+            "grad": sum(int(b.grad_workers.sum()) for b in parts),
+            "restarts": sum(int(b.restart_workers.sum()) for b in parts)}
+
+
+_span = jax.profiler.TraceAnnotation
+
+
 class DecentralizedTrainer:
     """Runs one algorithm on one model/dataset under one straggler model."""
 
@@ -277,6 +317,7 @@ class DecentralizedTrainer:
         self._fused_fold = None     # jitted fused_metrics_fold
         self._trace = None          # TraceRecorder (host-side buffers)
         self.last_trace = None      # finalized Trace of the latest run
+        self.counters = RunCounters()
 
     def _cast(self, tree):
         """Apply the worker-state dtype policy to a pytree's float leaves."""
@@ -409,50 +450,60 @@ class DecentralizedTrainer:
         return etas
 
     def _dispatch_block(self, batch: EventBatch, rounds: int,
-                        target: Optional[int] = None) -> None:
-        """One compiled call: pad to the block shape, advance (W, S, y, ptr)."""
+                        target: Optional[int] = None) -> int:
+        """One compiled call: pad to the block shape, advance (W, S, y, ptr).
+
+        The host work that makes the call's arguments runs under the
+        ``runner:pack`` span, the enqueue alone under ``dispatch:scan``.
+        Returns the scan rows dispatched, padding included.
+        """
+        with _span("runner:pack"):
+            xs, rows = self._pack_block(batch, rounds, target)
+        self._log.log("block_dispatch", mode="scan", events=batch.E,
+                      padded=rows, rounds=rounds)
+        with _span("dispatch:scan"):
+            if self.telemetry:
+                (self.W, self.S, self.y, self._ptr, self._metrics) = \
+                    self._scan(self.W, self.S, self.y, self._ptr,
+                               self._metrics, self._pools, *xs)
+            else:
+                self.W, self.S, self.y, self._ptr = self._scan(
+                    self.W, self.S, self.y, self._ptr, self._pools, *xs)
+        return rows
+
+    def _pack_block(self, batch: EventBatch, rounds: int,
+                    target: Optional[int]) -> Tuple[tuple, int]:
+        """The dense block's event arrays on the device, and its rows."""
         E = batch.E
         if target is None:
             target = self.block_size
         if E < target:
             batch = batch.pad_to(target)
         etas = self._etas_for(batch.E, E, rounds)
-        args = (
-            self.W, self.S, self.y, self._ptr,
-            jnp.asarray(batch.P, dtype=jnp.float32),
-            jnp.asarray(batch.grad_workers),
-            jnp.asarray(batch.restart_workers),
-            jnp.asarray(etas, dtype=jnp.float32),
-        )
-        # logged for every dispatch (no-op without a run log): the wall-
-        # clock track of repro.obs.trace is built from these records
-        self._log.log("block_dispatch", mode="scan", events=E,
-                      padded=batch.E, rounds=rounds)
+        xs = (jnp.asarray(batch.P, dtype=jnp.float32),
+              jnp.asarray(batch.grad_workers),
+              jnp.asarray(batch.restart_workers),
+              jnp.asarray(etas, dtype=jnp.float32))
         if not self.telemetry:
-            with jax.profiler.TraceAnnotation("dispatch:scan"):
-                self.W, self.S, self.y, self._ptr = self._scan(
-                    *args[:4], self._pools, *args[4:])
-            return
+            return xs, batch.E
         Ep = batch.E
         fin = batch.finish if batch.finish is not None \
             else np.broadcast_to(batch.times[:, None], (Ep, self.n))
-        with jax.profiler.TraceAnnotation("dispatch:scan"):
-            # casts happen host-side: a cross-dtype jnp.asarray would pay a
-            # per-block convert_element_type dispatch
-            (self.W, self.S, self.y, self._ptr, self._metrics) = self._scan(
-                *args[:4], self._metrics, self._pools, *args[4:],
-                jnp.asarray(np.asarray(batch.times, dtype=np.float32)),
-                jnp.asarray(np.asarray(fin, dtype=np.float32)),
-                jnp.asarray(np.arange(rounds, rounds + Ep, dtype=np.int32)),
-                jnp.asarray(np.asarray(batch.param_copies_sent,
-                                       dtype=np.int32)),
-            )
+        # casts happen host-side: a cross-dtype jnp.asarray would pay a
+        # per-block convert_element_type dispatch
+        return xs + (
+            jnp.asarray(np.asarray(batch.times, dtype=np.float32)),
+            jnp.asarray(np.asarray(fin, dtype=np.float32)),
+            jnp.asarray(np.arange(rounds, rounds + Ep, dtype=np.int32)),
+            jnp.asarray(np.asarray(batch.param_copies_sent,
+                                   dtype=np.int32)),
+        ), Ep
 
-    def _dispatch_sparse_block(self, batch: SparseEventBatch, rounds: int,
-                               target: Optional[int] = None,
-                               lane_off: Optional[np.ndarray] = None,
-                               lane_ts: Optional[np.ndarray] = None) -> None:
-        """One compiled call over active-set arrays: O(A·D) per event.
+    def _pack_sparse_block(self, batch: SparseEventBatch, rounds: int,
+                           target: int,
+                           lane_off: Optional[np.ndarray] = None,
+                           lane_ts: Optional[np.ndarray] = None) -> tuple:
+        """One sparse block's event arrays on the device: O(A·D) per event.
 
         ``lane_off`` marks ``batch`` as the output of ``merge_event_groups``:
         a (E, A) int array of absolute source-event offsets per lane, from
@@ -460,11 +511,9 @@ class DecentralizedTrainer:
         its source event would have used — the decay schedule is indexed by
         event, not by scan step, so merging stays bit-exact).  ``lane_ts``
         (telemetry, merged path only) carries the matching per-lane source
-        event clocks, gathered the same way.
+        event clocks, gathered the same way.  Returns ``(xs, log fields)``.
         """
         E = batch.E
-        if target is None:
-            target = self.block_size
         if E < target:
             batch = batch.pad_to(target)
         if lane_off is None:
@@ -473,22 +522,15 @@ class DecentralizedTrainer:
             etas = np.zeros((batch.E, batch.A))
             etas[:E] = self.eta0 * self.eta_decay ** (
                 (rounds + lane_off) // self.eta_decay_every)
-        args = (
-            self.W, self.S, self.y, self._ptr,
-            jnp.asarray(batch.workers),
-            jnp.asarray(batch.P_sub, dtype=jnp.float32),
-            jnp.asarray(batch.grad_workers),
-            jnp.asarray(batch.restart_workers),
-            jnp.asarray(etas, dtype=jnp.float32),
-        )
-        self._log.log("block_dispatch", mode="sparse_scan", events=E,
-                      padded=batch.E, lanes=batch.A, rounds=rounds,
-                      merged=lane_off is not None)
+        xs = (jnp.asarray(batch.workers),
+              jnp.asarray(batch.P_sub, dtype=jnp.float32),
+              jnp.asarray(batch.grad_workers),
+              jnp.asarray(batch.restart_workers),
+              jnp.asarray(etas, dtype=jnp.float32))
+        log = dict(events=E, padded=batch.E, lanes=batch.A, rounds=rounds,
+                   merged=lane_off is not None)
         if not self.telemetry:
-            with jax.profiler.TraceAnnotation("dispatch:sparse_scan"):
-                self.W, self.S, self.y, self._ptr = self._sparse(
-                    *args[:4], self._pools, *args[4:])
-            return
+            return xs, log
         Ep, A = batch.E, batch.A
         # Per-lane event indices and clocks: every lane of an unmerged row
         # shares the row's event; a merged row's lanes keep their source
@@ -507,18 +549,34 @@ class DecentralizedTrainer:
             ts = np.zeros((Ep, A))
             ts[:E] = lane_ts
         fin = batch.finish if batch.finish is not None else ts
-        with jax.profiler.TraceAnnotation("dispatch:sparse_scan"):
-            # casts happen host-side: a cross-dtype jnp.asarray would pay a
-            # per-block convert_element_type dispatch
-            (self.W, self.S, self.y, self._ptr,
-             self._metrics) = self._sparse(
-                *args[:4], self._metrics, self._pools, *args[4:],
-                jnp.asarray(np.asarray(ts, dtype=np.float32)),
-                jnp.asarray(np.asarray(fin, dtype=np.float32)),
-                jnp.asarray(ks),
-                jnp.asarray(np.asarray(batch.param_copies_sent,
-                                       dtype=np.int32)),
-            )
+        # casts happen host-side: a cross-dtype jnp.asarray would pay a
+        # per-block convert_element_type dispatch
+        return xs + (
+            jnp.asarray(np.asarray(ts, dtype=np.float32)),
+            jnp.asarray(np.asarray(fin, dtype=np.float32)),
+            jnp.asarray(ks),
+            jnp.asarray(np.asarray(batch.param_copies_sent,
+                                   dtype=np.int32)),
+        ), log
+
+    def _launch_sparse(self, blocks: List[tuple]) -> Tuple[int, int]:
+        """Enqueue packed sparse blocks in order, each alone under the
+        ``dispatch:sparse_scan`` span; returns (blocks, scan rows)."""
+        rows = 0
+        for xs, log in blocks:
+            self._log.log("block_dispatch", mode="sparse_scan", **log)
+            rows += log["padded"]
+            with _span("dispatch:sparse_scan"):
+                if self.telemetry:
+                    (self.W, self.S, self.y, self._ptr,
+                     self._metrics) = self._sparse(
+                        self.W, self.S, self.y, self._ptr, self._metrics,
+                        self._pools, *xs)
+                else:
+                    self.W, self.S, self.y, self._ptr = self._sparse(
+                        self.W, self.S, self.y, self._ptr, self._pools,
+                        *xs)
+        return len(blocks), rows
 
     def _events_per_step(self, A: int) -> int:
         """Events merged per scan step at lane width ``A`` (the blocking K).
@@ -541,8 +599,19 @@ class DecentralizedTrainer:
         return int(np.clip(64 // max(A, 1), 1, 16))
 
     def _dispatch_sparse_chunk(self, batch: SparseEventBatch, rounds: int,
-                               cap: int) -> None:
+                               cap: int) -> Tuple[int, int]:
         """Advance the carry through one same-bucket packed chunk.
+
+        All of the chunk's blocks are packed under one ``runner:pack``
+        span, then enqueued in order.  Returns (blocks, scan rows).
+        """
+        with _span("runner:pack"):
+            blocks = self._pack_sparse_chunk(batch, rounds, cap)
+        return self._launch_sparse(blocks)
+
+    def _pack_sparse_chunk(self, batch: SparseEventBatch, rounds: int,
+                           cap: int) -> List[tuple]:
+        """The packed blocks of one same-bucket chunk, in stream order.
 
         With K > 1 the chunk is first folded by ``merge_event_groups`` —
         runs of ≤K consecutive events with pairwise-disjoint worker sets
@@ -552,27 +621,20 @@ class DecentralizedTrainer:
         """
         K = self._events_per_step(batch.A)
         if K <= 1:
-            start = 0
-            while start < batch.E:
-                stop = min(batch.E, start + cap)
-                self._dispatch_sparse_block(
-                    batch.slice(start, stop), rounds + start, cap)
-                start = stop
-            return
+            return [self._pack_sparse_block(batch.slice(start, stop),
+                                            rounds + start, cap)
+                    for start, stop in _cuts(batch.E, cap)]
         merged, lane_off = merge_event_groups(batch, K)
         g_cap = max(1, cap // K)
         # telemetry: lane-level source-event clocks, gathered once per chunk
         lane_ts = batch.times[lane_off] if self.telemetry else None
-        start = 0
-        while start < merged.E:
-            stop = min(merged.E, start + g_cap)
-            # lane_off carries *absolute* source offsets within ``batch``,
-            # so ``rounds`` stays the chunk base across slices.
-            self._dispatch_sparse_block(
-                merged.slice(start, stop), rounds, g_cap,
-                lane_off=lane_off[start:stop],
-                lane_ts=None if lane_ts is None else lane_ts[start:stop])
-            start = stop
+        # lane_off carries *absolute* source offsets within ``batch``, so
+        # ``rounds`` stays the chunk base across slices.
+        return [self._pack_sparse_block(
+                    merged.slice(start, stop), rounds, g_cap,
+                    lane_off=lane_off[start:stop],
+                    lane_ts=None if lane_ts is None else lane_ts[start:stop])
+                for start, stop in _cuts(merged.E, g_cap)]
 
     # Base chunk length for the narrowest bucket of a multi-bucket ladder.
     # Chunks must be short: a DSGD-AAU stream switches buckets every ~4
@@ -604,7 +666,7 @@ class DecentralizedTrainer:
                    // (buckets[b] * buckets[b]))
 
     def _dispatch_bucketed(self, bucketed: BucketedSparseEventBatch,
-                           rounds: int, target: int) -> None:
+                           rounds: int, target: int) -> Tuple[int, int]:
         """Advance the carry through a bucketed block, in stream order.
 
         State updates are sequential, so buckets are *not* replayed whole:
@@ -616,13 +678,16 @@ class DecentralizedTrainer:
         per bucket).  Events therefore execute in exactly the per-event
         order — the bucketed path's results are bit-exact against the dense
         scan — while a typical DSGD-AAU event pays for ~16 lanes instead
-        of n.
+        of n.  Every segment is packed under one ``runner:pack`` span before
+        the first block is enqueued.  Returns (blocks, scan rows).
         """
-        for b, off, seg in bucketed.segment_batches():
-            cap = self._bucket_cap(bucketed.buckets, b, target)
-            self._log.log("bucket_segment", A=int(bucketed.buckets[b]),
-                          events=seg.E, rounds=rounds + off)
-            self._dispatch_sparse_chunk(seg, rounds + off, cap)
+        with _span("runner:pack"):
+            blocks = [
+                blk for b, off, seg in bucketed.segment_batches()
+                for blk in self._pack_sparse_chunk(
+                    seg, rounds + off,
+                    self._bucket_cap(bucketed.buckets, b, target))]
+        return self._launch_sparse(blocks)
 
     def _accum_occupancy(self, rows: List[Dict[str, float]]) -> None:
         """Fold one chunk's per-rung packing stats into the run aggregate."""
@@ -640,21 +705,22 @@ class DecentralizedTrainer:
         """Drain the device counters once (logged before ``run_end``)."""
         if not self.telemetry or self._metrics is None:
             return None
-        if self._fused_payload:
-            # fold the whole fused run's streamed event identities in one
-            # compiled call (event indices restart at 0 with the per-run
-            # counter reset, so k0 = 0)
-            t_ev, i_seq, p_seq, t_raw = (
-                jnp.concatenate(xs) if len(xs) > 1 else xs[0]
-                for xs in zip(*self._fused_payload))
-            self._metrics = self._fused_fold(
-                self._metrics, i_seq, p_seq, t_raw, t_ev,
-                int(self.scheduler.fused_spec()["copies_pair"]),
-                jnp.int32(0))
-            self._fused_payload = []
-        summary = metrics_summary(
-            self._metrics, t_end,
-            n_minus_1_bound=self.scheduler.name == "dsgd_aau")
+        with _span("runner:drain"):
+            if self._fused_payload:
+                # fold the whole fused run's streamed event identities in
+                # one compiled call (event indices restart at 0 with the
+                # per-run counter reset, so k0 = 0)
+                t_ev, i_seq, p_seq, t_raw = (
+                    jnp.concatenate(xs) if len(xs) > 1 else xs[0]
+                    for xs in zip(*self._fused_payload))
+                self._metrics = self._fused_fold(
+                    self._metrics, i_seq, p_seq, t_raw, t_ev,
+                    int(self.scheduler.fused_spec()["copies_pair"]),
+                    jnp.int32(0))
+                self._fused_payload = []
+            summary = metrics_summary(
+                self._metrics, t_end,
+                n_minus_1_bound=self.scheduler.name == "dsgd_aau")
         summary["comm_bytes_per_copy"] = self.param_count * self.dtype.itemsize
         if self._bucket_occ:
             summary["bucket_occupancy"] = [
@@ -686,7 +752,8 @@ class DecentralizedTrainer:
         if not self.trace or self._trace is None:
             return None
         if self._fused_payload:
-            host = drain_fused_payload(self._fused_payload)
+            with _span("runner:drain"):
+                host = drain_fused_payload(self._fused_payload)
             self._trace.record_fused(
                 *host,
                 copies_pair=int(self.scheduler.fused_spec()["copies_pair"]))
@@ -795,37 +862,37 @@ class DecentralizedTrainer:
         eval_every: int = 10,
     ) -> RunResult:
         assert max_events or max_time, "bound the run by events or virtual time"
-        if self.telemetry:
-            # fresh counters per run: event indices (the staleness clock)
-            # restart at 0 every run, so carried-over restart marks from a
-            # previous run would alias as negative staleness
-            self._metrics = self._init_metrics(self.n)
-            self._bucket_occ = {}
-        if self.telemetry or self.trace:
-            self._fused_payload = []
-        if self.trace:
-            self._trace = TraceRecorder(self.n)
-        self._log.log("run_start", algorithm=self.scheduler.name, n=self.n,
-                      mode=self.mode, max_events=max_events,
-                      max_time=max_time, eval_every=eval_every,
-                      dtype=str(self.dtype), telemetry=self.telemetry,
-                      trace=self.trace)
-        if self.mode == "fused" or getattr(self.scheduler, "horizon", None):
-            self._log.warn_once(
-                "rng_order",
-                "event stream is a different-but-deterministic RNG-order "
-                "realization (horizon batching / fused generation): "
-                "distributionally identical to the exact per-event stream, "
-                "not bit-identical to it.", warn=False)
-        with self._maybe_sanitized():
-            if self.mode == "fused":
-                return self._run_fused(max_events, max_time, eval_every)
-            if self.mode == "sparse_scan":
-                return self._run_sparse_stream(max_events, max_time,
-                                               eval_every)
-            if self.mode == "scan":
-                return self._run_scan(max_events, max_time, eval_every)
-            return self._run_per_event(max_events, max_time, eval_every)
+        with _span("runner:run") as span:
+            if self.telemetry:
+                # fresh counters per run: event indices (the staleness clock)
+                # restart at 0 every run, so carried-over restart marks from a
+                # previous run would alias as negative staleness
+                self._metrics = self._init_metrics(self.n)
+                self._bucket_occ = {}
+            if self.telemetry or self.trace:
+                self._fused_payload = []
+            if self.trace:
+                self._trace = TraceRecorder(self.n)
+            self._log.log("run_start", algorithm=self.scheduler.name, n=self.n,
+                          mode=self.mode, max_events=max_events,
+                          max_time=max_time, eval_every=eval_every,
+                          dtype=str(self.dtype), telemetry=self.telemetry,
+                          trace=self.trace)
+            if self.mode == "fused" or getattr(self.scheduler, "horizon", None):
+                self._log.warn_once(
+                    "rng_order",
+                    "event stream is a different-but-deterministic RNG-order "
+                    "realization (horizon batching / fused generation): "
+                    "distributionally identical to the exact per-event stream, "
+                    "not bit-identical to it.", warn=False)
+            drive = {"fused": self._run_fused,
+                     "sparse_scan": self._run_sparse_stream,
+                     "scan": self._run_scan}.get(self.mode, self._run_per_event)
+            before = dataclasses.replace(self.counters)
+            with self._maybe_sanitized():
+                res = drive(max_events, max_time, eval_every)
+            span.set_metadata(**self.counters.since(before))
+        return res
 
     def _maybe_sanitized(self):
         """The runtime sanitizer context when enabled, else a no-op.
@@ -849,12 +916,21 @@ class DecentralizedTrainer:
         t = 0.0
         k = -1
         rounds = 0
-        for ev in self.scheduler.events():
+        with _span("runner:gen"):    # a new event process per run
+            stream = self.scheduler.events()
+        while True:
+            with _span("runner:gen"):
+                ev = next(stream, None)
+            if ev is None:
+                break
             if max_events is not None and ev.k >= max_events:
                 break
             if max_time is not None and ev.time > max_time:
                 break
             k, t = ev.k, ev.time
+            self.counters.add(events=1, active=len(ev.workers),
+                              grad=ev.grad_lanes.sum(),
+                              restarts=ev.restart_lanes.sum())
             comm += ev.param_copies_sent
             active_sizes.append(ev.n_active)
             if self.trace:
@@ -911,13 +987,13 @@ class DecentralizedTrainer:
         k = -1
         rounds = 0
         buf = []
-        stream = self.scheduler.events()
+        with _span("runner:gen"):    # a new event process per run
+            stream = self.scheduler.events()
         exhausted = False
         while not exhausted:
-            try:
-                ev = next(stream)
-            except StopIteration:  # finite custom stream: flush what we have
-                ev = None
+            with _span("runner:gen"):
+                # None: a finite custom stream ended; flush what we have
+                ev = next(stream, None)
             if (ev is None
                     or (max_events is not None and ev.k >= max_events)
                     or (max_time is not None and ev.time > max_time)):
@@ -938,9 +1014,14 @@ class DecentralizedTrainer:
                 # recorded pre-pack, pre-pad: the same object events the
                 # per-event reference replays, so the traces bit-match
                 self._trace.record_events(buf)
-            self._dispatch_block(
-                EventBatch.from_events(buf, edge_bound=bound), rounds,
-                target)
+            with _span("runner:pack"):
+                batch = EventBatch.from_events(buf, edge_bound=bound)
+            rows = self._dispatch_block(batch, rounds, target)
+            self.counters.add(
+                events=batch.E, blocks=1, rows=rows,
+                active=sum(len(ev.workers) for ev in buf),
+                grad=batch.grad_workers.sum(),
+                restarts=batch.restart_workers.sum())
             rounds += len(buf)
             buf = []
             if rounds % eval_every == 0:
@@ -954,7 +1035,8 @@ class DecentralizedTrainer:
     def _warn_pool_wrap(self, rounds: int) -> None:
         # host-side max: keeps this off the compile cache (a jnp.max here
         # would be the run's only reduce op — one more first-run compile)
-        max_ptr = int(np.max(jax.device_get(self._ptr))) if rounds else 0
+        with _span("runner:drain"):
+            max_ptr = int(np.max(jax.device_get(self._ptr))) if rounds else 0
         if max_ptr > self._pool_len:
             self._log.warn_once(
                 "pool_wrap",
@@ -986,7 +1068,9 @@ class DecentralizedTrainer:
         t = 0.0
         k = -1
         rounds = 0
-        stream = self.scheduler.packed_stream(native=self.native_generation)
+        with _span("runner:gen"):    # a new event process per run
+            stream = self.scheduler.packed_stream(
+                native=self.native_generation)
         exhausted = False
         while not exhausted:
             until_eval = eval_every - rounds % eval_every
@@ -995,7 +1079,8 @@ class DecentralizedTrainer:
                 want = min(want, max_events - rounds)
             if want <= 0:
                 break
-            chunk = stream.next_chunk(want)
+            with _span("runner:gen"):
+                chunk = stream.next_chunk(want)
             if chunk is None:
                 break
             if chunk.E < want:  # finite custom stream ended mid-chunk
@@ -1019,14 +1104,17 @@ class DecentralizedTrainer:
             if isinstance(chunk, BucketedSparseEventBatch):
                 if self.telemetry:
                     self._accum_occupancy(chunk.occupancy())
-                self._dispatch_bucketed(chunk, rounds, target)
+                blocks, rows = self._dispatch_bucketed(chunk, rounds, target)
             else:
                 if self.telemetry:
                     self._accum_occupancy([{
                         "A": int(chunk.A), "events": int(chunk.E),
                         "lane_fill": float(chunk.n_workers.sum())
                         / max(chunk.E * chunk.A, 1)}])
-                self._dispatch_sparse_chunk(chunk, rounds, target)
+                blocks, rows = self._dispatch_sparse_chunk(chunk, rounds,
+                                                           target)
+            self.counters.add(events=chunk.E, blocks=blocks, rows=rows,
+                              **_lane_counts(chunk))
             rounds += chunk.E
             if rounds % eval_every == 0:
                 eval_buf = self._record_eval(eval_buf, len(meta))
@@ -1097,17 +1185,24 @@ class DecentralizedTrainer:
         while rounds < max_events:
             until_eval = eval_every - rounds % eval_every
             E = min(blk, until_eval, max_events - rounds)
-            factors, picks = sched.fused_draws(E)
-            # f32 cast on host: jnp.asarray of an f64 array would insert a
-            # convert_element_type op (a first-run compile); a same-dtype
-            # asarray is a pure device put
-            etas = np.asarray(self._etas_for(E, E, rounds), dtype=np.float32)
-            xs = (jnp.asarray(factors, dtype=jnp.float32),
-                  jnp.asarray(picks, dtype=jnp.float32),
-                  jnp.asarray(etas, dtype=jnp.float32))
+            with _span("runner:gen"):
+                factors, picks = sched.fused_draws(E)
+            with _span("runner:pack"):
+                # f32 cast on host: jnp.asarray of an f64 array would insert
+                # a convert_element_type op (a first-run compile); a
+                # same-dtype asarray is a pure device put
+                etas = np.asarray(self._etas_for(E, E, rounds),
+                                  dtype=np.float32)
+                xs = (jnp.asarray(factors, dtype=jnp.float32),
+                      jnp.asarray(picks, dtype=jnp.float32),
+                      jnp.asarray(etas, dtype=jnp.float32))
             self._log.log("block_dispatch", mode="fused", events=E,
                           rounds=rounds)
-            with jax.profiler.TraceAnnotation("dispatch:fused"):
+            # one finisher per event: its lane is the gradient and the
+            # restart lane
+            self.counters.add(events=E, blocks=1, rows=E, grad=E,
+                              restarts=E)
+            with _span("dispatch:fused"):
                 (self.W, self.S, self.y, self._ptr, times, lock_free,
                  comm_dev), ys = self._fused(
                     self.W, self.S, self.y, self._ptr, self._pools,
@@ -1130,7 +1225,8 @@ class DecentralizedTrainer:
         self._warn_pool_wrap(rounds)
         # one fetch; sliced on host (a device-side [:k] would compile a
         # slice executable on the first run)
-        vals = np.asarray(jax.device_get(eval_buf))[:len(meta)]
+        with _span("runner:drain"):
+            vals = np.asarray(jax.device_get(eval_buf))[:len(meta)]
         # comm is exact through f32 up to 2^24 copies; pair-event counts
         # (comm deltas / copies-per-pair) back out the mean active-set
         # size — 2 lanes per pair event, 1 per isolated-worker event.
@@ -1167,12 +1263,14 @@ class DecentralizedTrainer:
                       comm_dev: jax.Array) -> jax.Array:
         """Append one fused-mode history row ([loss, metric, t, comm]) —
         all eager device ops, no host sync; warmup() precompiles them."""
-        row = jnp.concatenate([
-            self._eval_accum(self.W, self.y, self.eval_batch),
-            jnp.stack([t_last, comm_dev.astype(jnp.float32)])])
-        if i == eval_buf.shape[0]:
-            eval_buf = jnp.concatenate([eval_buf, jnp.zeros_like(eval_buf)])
-        return eval_buf.at[jnp.asarray(i)].set(row)
+        with _span("runner:eval"):
+            row = jnp.concatenate([
+                self._eval_accum(self.W, self.y, self.eval_batch),
+                jnp.stack([t_last, comm_dev.astype(jnp.float32)])])
+            if i == eval_buf.shape[0]:
+                eval_buf = jnp.concatenate([eval_buf,
+                                            jnp.zeros_like(eval_buf)])
+            return eval_buf.at[jnp.asarray(i)].set(row)
 
     # -- on-device eval history -------------------------------------------
     def _ensure_eval_accum(self):
@@ -1193,17 +1291,20 @@ class DecentralizedTrainer:
         # shapes — warmup() precompiles it; the scatter into the history
         # buffer is a tiny eager device op (dynamic index: one executable
         # regardless of i or buffer growth).  No host sync anywhere.
-        row = self._eval_accum(self.W, self.y, self.eval_batch)
-        if i == eval_buf.shape[0]:  # max_time-bounded run outgrew the buffer
-            eval_buf = jnp.concatenate([eval_buf, jnp.zeros_like(eval_buf)])
-        return eval_buf.at[jnp.asarray(i)].set(row)
+        with _span("runner:eval"):
+            row = self._eval_accum(self.W, self.y, self.eval_batch)
+            if i == eval_buf.shape[0]:  # a max_time run outgrew the buffer
+                eval_buf = jnp.concatenate([eval_buf,
+                                            jnp.zeros_like(eval_buf)])
+            return eval_buf.at[jnp.asarray(i)].set(row)
 
     def _finish_scan(self, eval_buf, meta, k, t, comm, rounds,
                      active_sizes) -> RunResult:
         eval_buf = self._record_eval(eval_buf, len(meta))
         meta.append((k, t, comm,
                      float(np.mean(active_sizes)) if active_sizes else 0.0))
-        vals = np.asarray(jax.device_get(eval_buf[:len(meta)]))  # one fetch
+        with _span("runner:drain"):
+            vals = np.asarray(jax.device_get(eval_buf[:len(meta)]))  # one fetch
         history = [
             HistoryPoint(k=mk, time=mt, loss=float(vals[i, 0]),
                          metric=float(vals[i, 1]), comm_param_copies=mc,
@@ -1239,11 +1340,20 @@ class DecentralizedTrainer:
         )
 
     def _eval_now(self):
-        avg = debiased_average(self.W, self.y)
-        # explicit fetch: float() on the device scalars would be an implicit
-        # d2h sync (the runtime sanitizer's transfer guard rejects those)
-        loss, metric = jax.device_get(self._eval(avg, self.eval_batch))
+        with _span("runner:eval"):
+            avg = debiased_average(self.W, self.y)
+            # explicit fetch: float() on the device scalars would be an
+            # implicit d2h sync (the runtime sanitizer's transfer guard
+            # rejects those)
+            loss, metric = jax.device_get(self._eval(avg, self.eval_batch))
         return float(loss), float(metric)
+
+
+def _cuts(total: int, size: int) -> List[Tuple[int, int]]:
+    """``[start, stop)`` pieces of ``range(total)``, ``size`` long but the
+    last."""
+    return [(start, min(total, start + size))
+            for start in range(0, total, size)]
 
 
 def _identity_event(n: int):

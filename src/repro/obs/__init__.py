@@ -19,10 +19,11 @@ and a per-worker wait-blame decomposition — the "straggler tax" table
 that quantifies what DSGD-AAU's adaptive neighbor count saves.
 
 Around the device core, :class:`RunLogger` writes structured JSONL run
-logs (block dispatches, bucket-rung choices, compile events, pool-wrap
-warnings — every record wall-clock timestamped) replacing bare
-``warnings.warn``, and ``jax.named_scope`` annotations on the kernels
-and update bodies make ``--profile`` traces legible.
+logs (block dispatches, compile events, pool-wrap warnings) replacing
+bare ``warnings.warn``.  Wall-clock time lives in the JAX profiler's
+trace: the runner's ``runner:*`` / ``dispatch:*`` host spans and the
+compiled blocks' phase scopes (``grad``, ``mix``, ``sparse_gather``,
+``pool_select``, ``sparse_scatter``, ``s_update``).
 """
 from repro.obs.critical_path import (attribute_wait, critical_path,
                                      straggler_tax)
@@ -32,13 +33,12 @@ from repro.obs.metrics import (MetricsCarry, block_metrics_update,
                                sparse_metrics_update)
 from repro.obs.runlog import RunLogger
 from repro.obs.trace import (Trace, TraceRecorder, chrome_trace,
-                             drain_fused_payload, load_run_log, wall_track)
+                             drain_fused_payload)
 
 __all__ = [
     "MetricsCarry", "RunLogger", "Trace", "TraceRecorder",
     "attribute_wait", "block_metrics_update", "chrome_trace",
     "critical_path", "dense_metrics_update", "drain_fused_payload",
-    "fused_metrics_fold", "init_metrics", "load_run_log",
-    "metrics_summary", "sparse_metrics_update", "straggler_tax",
-    "wall_track",
+    "fused_metrics_fold", "init_metrics", "metrics_summary",
+    "sparse_metrics_update", "straggler_tax",
 ]

@@ -10,7 +10,6 @@ Events the trainer emits (the log schema, also documented in README):
 
 ``run_start``      n, mode, algorithm-ish metadata the caller passes
 ``block_dispatch`` mode, events, rounds — one per compiled block launch
-``bucket_segment`` bucket (lane width), events, offset — bucketed path
 ``compile``        key — first-time build of a jitted block (cache miss)
 ``pool_wrap``      the batch-pool reuse warning (also a ``warnings.warn``)
 ``rng_order``      horizon-batcher RNG-order notice (log-only)
@@ -18,10 +17,9 @@ Events the trainer emits (the log schema, also documented in README):
 ``run_end``        rounds, t, comm — final totals
 
 Every record additionally carries ``ts`` — wall-clock seconds since the
-logger was constructed (monotonic clock) — which is what lets
-``python -m repro.obs.trace`` rebuild a wall-time Perfetto track
-(per-block dispatch spans, per-rung segment spans, compile instants)
-from the log alone.
+logger was constructed (monotonic clock).  It is when the host logged the
+record, not when the device ran the work: device time is read from the
+JAX profiler's trace (docs/observability.md).
 
 ``warn_once(key, message, warn=True)`` dedupes by key for the logger's
 lifetime and forwards to :func:`warnings.warn` (stacklevel raised so the

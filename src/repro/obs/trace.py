@@ -26,29 +26,19 @@ Recording cost follows the drain-once discipline of the telemetry layer
   realization of the stream (see core/fused.py), so its trace is
   internally consistent rather than event-matched to the host modes'.
 
-The Chrome Trace Event Format exporter (:func:`chrome_trace`) renders two
-process tracks, loadable in Perfetto / ``chrome://tracing``:
-
-- **pid 0 — virtual time**: one thread per worker; ``compute`` spans
-  (previous restart → raw completion), ``wait`` spans (completion → event
-  commit, i.e. straggler/lock wait), and gossip edges as ``s``/``f`` flow
-  arrows between the coupled workers at the commit instant.
-- **pid 1 — wall clock**: built from :class:`~repro.obs.runlog.RunLogger`
-  records (every record carries a wall-clock ``ts``); ``block_dispatch``
-  spans on the dispatch thread, per-rung ``bucket_segment`` spans on one
-  thread per lane width A, ``compile`` instants.  Virtual-time cost and
-  wall-time cost per bucket rung sit side by side.
-
-``python -m repro.obs.trace RUN_LOG.jsonl`` builds the wall-clock track
-alone from a run-log file (no trainer needed).
+The Chrome Trace Event Format exporter (:func:`chrome_trace`) renders the
+virtual-time track, loadable in Perfetto / ``chrome://tracing``: one
+thread per worker; ``compute`` spans (previous restart → raw completion),
+``wait`` spans (completion → event commit, i.e. straggler/lock wait), and
+gossip edges as ``s``/``f`` flow arrows between the coupled workers at the
+commit instant.  Wall-clock time is not this module's: the runner's
+``runner:*`` / ``dispatch:*`` profiler spans and the compiled block's
+phase scopes put it in the JAX profiler's trace (docs/observability.md).
 """
 from __future__ import annotations
 
-import argparse
 import dataclasses
-import json
-import sys
-from typing import IO, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -57,9 +47,6 @@ __all__ = [
     "TraceRecorder",
     "drain_fused_payload",
     "chrome_trace",
-    "wall_track",
-    "load_run_log",
-    "main",
 ]
 
 
@@ -274,29 +261,14 @@ def drain_fused_payload(payload: Sequence) -> Tuple[np.ndarray, ...]:
 
 #: 1 unit of virtual time renders as 1 s (Chrome trace ``ts`` is in µs).
 _VIRT_US = 1e6
-#: Wall-clock ``ts`` fields are seconds since logger construction.
-_WALL_US = 1e6
 
 
-def chrome_trace(trace: Optional[Trace] = None,
-                 run_log: Optional[Sequence[Dict]] = None) -> Dict:
-    """Build a Chrome Trace Event Format document (JSON-serializable).
-
-    ``trace`` fills the virtual-time process (pid 0, one thread per
-    worker); ``run_log`` (a list of RunLogger records) fills the
-    wall-clock process (pid 1).  Either may be omitted.
-    """
-    events: List[Dict] = []
-    if trace is not None:
-        events.extend(_virtual_track(trace))
-    if run_log is not None:
-        events.extend(wall_track(run_log))
-    other = {}
-    if trace is not None:
-        other = {"algorithm": trace.algorithm, "mode": trace.mode,
-                 "n": trace.n, "events": trace.n_events}
-    return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": other}
+def chrome_trace(trace: Trace) -> Dict:
+    """A Chrome Trace Event Format document (JSON-serializable) of a run's
+    virtual-time track: one thread per worker."""
+    return {"traceEvents": _virtual_track(trace), "displayTimeUnit": "ms",
+            "otherData": {"algorithm": trace.algorithm, "mode": trace.mode,
+                          "n": trace.n, "events": trace.n_events}}
 
 
 def _virtual_track(trace: Trace, pid: int = 0) -> List[Dict]:
@@ -344,108 +316,3 @@ def _virtual_track(trace: Trace, pid: int = 0) -> List[Dict]:
                     "bp": "e", "pid": pid, "tid": b, "ts": ts, "id": fid,
                     "args": {"event": k}})
     return out
-
-
-#: Wall-track thread ids: dispatch spans on tid 0; a bucketed run's
-#: per-rung segments each get the rung's lane width A as their tid.
-_WALL_DISPATCH_TID = 0
-
-
-def wall_track(records: Sequence[Dict], pid: int = 1) -> List[Dict]:
-    """Wall-clock spans from RunLogger records (each carries ``ts``).
-
-    ``block_dispatch`` / ``bucket_segment`` records mark span *starts*;
-    a span's duration is the gap to the next timestamped record (the
-    dispatch loop logs before launching each block, so consecutive
-    records bracket the launch + host packing work).  ``compile`` and the
-    remaining lifecycle records render as instants.
-    """
-    recs = [r for r in records if isinstance(r.get("ts"), (int, float))]
-    recs.sort(key=lambda r: r["ts"])
-    out: List[Dict] = [{
-        "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-        "args": {"name": "wall clock (run log)"},
-    }, {
-        "name": "thread_name", "ph": "M", "pid": pid,
-        "tid": _WALL_DISPATCH_TID, "args": {"name": "dispatch"},
-    }]
-    rungs = sorted({int(r["A"]) for r in recs
-                    if r.get("event") == "bucket_segment" and "A" in r})
-    for a in rungs:
-        out.append({"name": "thread_name", "ph": "M", "pid": pid,
-                    "tid": a, "args": {"name": f"rung A={a}"}})
-    for idx, rec in enumerate(recs):
-        ts = float(rec["ts"]) * _WALL_US
-        nxt = (float(recs[idx + 1]["ts"]) * _WALL_US
-               if idx + 1 < len(recs) else ts)
-        kind = rec.get("event", "?")
-        args = {k: v for k, v in rec.items() if k not in ("event", "ts")}
-        if kind == "block_dispatch":
-            out.append({
-                "name": f"dispatch:{rec.get('mode', '?')}",
-                "cat": "dispatch", "ph": "X", "pid": pid,
-                "tid": _WALL_DISPATCH_TID, "ts": ts,
-                "dur": max(nxt - ts, 0.0), "args": args,
-            })
-        elif kind == "bucket_segment":
-            out.append({
-                "name": f"segment A={rec.get('A', '?')}",
-                "cat": "dispatch", "ph": "X", "pid": pid,
-                "tid": int(rec.get("A", 0)), "ts": ts,
-                "dur": max(nxt - ts, 0.0), "args": args,
-            })
-        else:
-            out.append({
-                "name": kind, "cat": "lifecycle", "ph": "i", "pid": pid,
-                "tid": _WALL_DISPATCH_TID, "ts": ts, "s": "t",
-                "args": args,
-            })
-    return out
-
-
-# -- run-log CLI -------------------------------------------------------------
-
-def load_run_log(path_or_fh: Union[str, IO[str]]) -> List[Dict]:
-    """Parse a RunLogger JSONL file; malformed lines are skipped."""
-    if hasattr(path_or_fh, "read"):
-        lines = path_or_fh.read().splitlines()
-    else:
-        with open(path_or_fh, "r", encoding="utf-8") as fh:
-            lines = fh.read().splitlines()
-    records = []
-    for line in lines:
-        line = line.strip()
-        if not line:
-            continue
-        try:
-            rec = json.loads(line)
-        except json.JSONDecodeError:
-            continue
-        if isinstance(rec, dict):
-            records.append(rec)
-    return records
-
-
-def main(argv: Optional[Sequence[str]] = None) -> int:
-    ap = argparse.ArgumentParser(
-        prog="python -m repro.obs.trace",
-        description="Convert a RunLogger JSONL run log into a Chrome Trace "
-                    "Event Format file (wall-clock track) for Perfetto / "
-                    "chrome://tracing.")
-    ap.add_argument("run_log", help="path to the run log (JSONL)")
-    ap.add_argument("-o", "--out", default=None,
-                    help="output path (default: <run_log>.trace.json)")
-    args = ap.parse_args(argv)
-    records = load_run_log(args.run_log)
-    doc = chrome_trace(run_log=records)
-    out = args.out or (args.run_log + ".trace.json")
-    with open(out, "w", encoding="utf-8") as fh:
-        json.dump(doc, fh)
-    spans = sum(1 for e in doc["traceEvents"] if e.get("ph") == "X")
-    print(f"wrote {out}: {len(doc['traceEvents'])} trace events "
-          f"({spans} spans) from {len(records)} log records")
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

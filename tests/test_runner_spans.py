@@ -1,0 +1,143 @@
+"""The runner's profiler spans and run counters.
+
+A traced run holds, inside each ``runner:run`` span, the runner's
+``runner:gen`` / ``runner:pack`` / ``runner:eval`` / ``runner:drain``
+spans and the ``dispatch:*`` enqueue spans; the uploads of a block's
+arguments sit under ``runner:pack``, never under ``dispatch:*``; and the
+``runner:run`` span carries the run's :class:`RunCounters` as metadata.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import topology
+from repro.core.baselines import make_scheduler
+from repro.core.runner import DecentralizedTrainer, RunCounters
+from repro.core.straggler import StragglerModel
+from repro.data.synthetic import ClassificationData
+
+N = 16
+DATA = ClassificationData(n_workers=N, d=16, n_classes=4,
+                          samples_per_worker=64, seed=0)
+CHILDREN = ("runner:gen", "runner:pack", "runner:eval", "runner:drain")
+UPLOAD = "DevicePut"        # host events of a host-to-device upload
+
+
+def loss_fn(params, batch):
+    logp = jax.nn.log_softmax(batch["x"] @ params["w"])
+    return -jnp.mean(jnp.take_along_axis(logp, batch["y"][:, None], axis=1))
+
+
+def _trainer(alg, mode, **kw):
+    g = topology.erdos_renyi(N, 0.4, seed=3)
+    sm = StragglerModel(n=N, straggler_prob=0.2, slowdown=6.0, seed=0)
+    return DecentralizedTrainer(
+        make_scheduler(alg, g, sm), loss_fn,
+        lambda k: {"w": jax.random.normal(k, (16, 4)) * 0.1},
+        lambda w, s: DATA.batch(w, s, batch_size=8), DATA.eval_batch(64),
+        mode=mode, block_size=8, **kw)
+
+
+def _traced_run(tmp_path, tr, **run_kw):
+    """Host events of one traced ``run()``: [(name, start, end, stats)]."""
+    from jax.profiler import ProfileData
+
+    tr.warmup()
+    tr.run(**run_kw)        # compiles everything the traced run uses
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res = tr.run(**run_kw)
+    finally:
+        jax.profiler.stop_trace()
+    (xp,) = tmp_path.rglob("*.xplane.pb")
+    evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+            {k: v for k, v in ev.stats})
+           for plane in ProfileData.from_file(str(xp)).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events]
+    return res, evs
+
+
+def _inside(ev, outer):
+    return outer[1] <= ev[1] and ev[2] <= outer[2]
+
+
+MODES = [("dsgd_aau", "sparse_scan"), ("ad_psgd", "sparse_scan"),
+         ("dsgd_aau", "scan")]
+
+
+@pytest.mark.parametrize("alg, mode", MODES)
+def test_spans_nest_inside_run(tmp_path, alg, mode):
+    tr = _trainer(alg, mode)
+    _, evs = _traced_run(tmp_path, tr, max_events=32, eval_every=16)
+    (run,) = [e for e in evs if e[0] == "runner:run"]
+    for name in CHILDREN + ("dispatch:",):
+        spans = [e for e in evs if e[0].startswith(name)]
+        assert spans, name
+        assert all(_inside(e, run) for e in spans), name
+    packs = [e for e in evs if e[0] == "runner:pack"]
+    dispatches = [e for e in evs if e[0].startswith("dispatch:")]
+    # every enqueue comes after a pack, and no pack is inside an enqueue
+    assert len(dispatches) >= 1 and packs[0][1] < dispatches[0][1]
+    assert not any(_inside(p, d) for p in packs for d in dispatches)
+
+
+@pytest.mark.parametrize("alg, mode", MODES)
+def test_uploads_are_packed_not_dispatched(tmp_path, alg, mode):
+    tr = _trainer(alg, mode)
+    _, evs = _traced_run(tmp_path, tr, max_events=32, eval_every=16)
+    uploads = [e for e in evs if UPLOAD in e[0]]
+    packs = [e for e in evs if e[0] == "runner:pack"]
+    dispatches = [e for e in evs if e[0].startswith("dispatch:")]
+    assert uploads
+    assert not any(_inside(u, d) for u in uploads for d in dispatches)
+    # the block's event arrays are uploaded while packing
+    assert sum(any(_inside(u, p) for p in packs) for u in uploads) >= 4
+
+
+@pytest.mark.parametrize("alg, mode", MODES)
+def test_run_span_carries_the_run_counters(tmp_path, alg, mode):
+    tr = _trainer(alg, mode)
+    res, evs = _traced_run(tmp_path, tr, max_events=32, eval_every=16)
+    (run,) = [e for e in evs if e[0] == "runner:run"]
+    stats = run[3]
+    assert stats["events"] == res.total_events == 32
+    # the traced run is the second of two equal runs
+    assert tr.counters.events == 2 * 32
+    assert stats["blocks"] == len([e for e in evs
+                                   if e[0].startswith("dispatch:")])
+    assert stats["rows"] >= stats["blocks"]
+    assert 0 < stats["grad"] <= stats["active"]
+
+
+def test_counters_count_events_and_lanes():
+    tr = _trainer("dsgd_aau", "sparse_scan", trace=True)
+    res = tr.run(max_events=40, eval_every=20)
+    c = tr.counters
+    t = tr.last_trace
+    assert c.events == res.total_events == t.n_events == 40
+    assert c.active == t.n_lanes
+    assert c.grad == int(t.lane_grad.sum())
+    assert c.restarts == int(t.lane_restart.sum())
+    assert c.blocks >= 1 and c.rows >= c.blocks
+
+
+def test_warmup_counts_nothing():
+    tr = _trainer("ad_psgd", "sparse_scan")
+    tr.warmup()
+    assert tr.counters == RunCounters()
+
+
+def test_since_is_a_difference():
+    a = RunCounters(events=3, blocks=1, rows=8, active=6, grad=3,
+                    restarts=3)
+    b = RunCounters(events=5, blocks=2, rows=16, active=10, grad=5,
+                    restarts=4)
+    assert b.since(a) == {"events": 2, "blocks": 1, "rows": 8, "active": 4,
+                          "grad": 2, "restarts": 1}
+    a.add(events=np.int64(2), blocks=1)
+    assert (a.events, a.blocks) == (5, 2)

@@ -132,7 +132,8 @@ class TestSparseEventBatchPacking:
             n_workers=np.zeros_like(batch.n_workers),
             P_sub=np.zeros_like(batch.P_sub),
             grad_workers=off, restart_workers=off)
-        tr._dispatch_sparse_block(noop.pad_to(tr.block_size), rounds=0)
+        tr._launch_sparse([tr._pack_sparse_block(
+            noop.pad_to(tr.block_size), 0, tr.block_size)])
         for a, b in zip(jax.tree.leaves(W0), jax.tree.leaves(tr.W)):
             np.testing.assert_array_equal(a, np.asarray(b))
         np.testing.assert_array_equal(np.asarray(tr._ptr), np.zeros(N))

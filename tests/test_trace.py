@@ -36,8 +36,7 @@ from repro.core.straggler import StragglerModel
 from repro.data.synthetic import ClassificationData
 from repro.obs.critical_path import (attribute_wait, critical_path,
                                      straggler_tax)
-from repro.obs.trace import Trace, chrome_trace, load_run_log, wall_track
-from repro.obs.trace import main as trace_main
+from repro.obs.trace import Trace, chrome_trace
 
 N = 16
 DATA = ClassificationData(n_workers=N, d=16, n_classes=4,
@@ -314,7 +313,7 @@ class TestChromeTraceExport:
     def test_virtual_track_schema(self):
         tr = _trainer("dsgd_aau", "sparse_scan")
         tr.run(max_events=60, eval_every=20)
-        doc = chrome_trace(trace=tr.last_trace)
+        doc = chrome_trace(tr.last_trace)
         _validate_chrome(doc)
         evs = doc["traceEvents"]
         assert any(e["ph"] == "X" and e["name"] == "compute" for e in evs)
@@ -325,42 +324,3 @@ class TestChromeTraceExport:
         names = {e["tid"] for e in evs
                  if e["ph"] == "M" and e["name"] == "thread_name"}
         assert names == set(range(N))
-
-    def test_wall_track_from_run_log(self, tmp_path):
-        log = tmp_path / "run.jsonl"
-        tr = _trainer("dsgd_aau", "sparse_scan", run_log=str(log))
-        tr.run(max_events=48, eval_every=16)
-        records = load_run_log(str(log))
-        assert all("ts" in r for r in records)
-        doc = chrome_trace(trace=tr.last_trace, run_log=records)
-        _validate_chrome(doc)
-        walls = [e for e in doc["traceEvents"] if e["pid"] == 1]
-        assert any(e["ph"] == "X" and e["name"].startswith("dispatch:")
-                   for e in walls)
-        assert any(e["ph"] == "i" for e in walls)  # lifecycle instants
-
-    def test_cli_round_trip(self, tmp_path, capsys):
-        log = tmp_path / "run.jsonl"
-        tr = _trainer("ad_psgd", "sparse_scan", run_log=str(log))
-        tr.run(max_events=48, eval_every=16)
-        out = tmp_path / "out.trace.json"
-        assert trace_main([str(log), "-o", str(out)]) == 0
-        assert "wrote" in capsys.readouterr().out
-        doc = json.loads(out.read_text())
-        _validate_chrome(doc)
-        assert any(e["ph"] == "X" for e in doc["traceEvents"])
-
-    def test_malformed_log_lines_skipped(self, tmp_path):
-        log = tmp_path / "bad.jsonl"
-        log.write_text('{"event": "a", "ts": 0.5}\nnot json\n\n[1, 2]\n')
-        records = load_run_log(str(log))
-        assert records == [{"event": "a", "ts": 0.5}]
-        _validate_chrome(chrome_trace(run_log=records))
-
-    def test_wall_track_span_durations_bracket(self):
-        recs = [{"event": "block_dispatch", "ts": 0.0, "mode": "scan"},
-                {"event": "block_dispatch", "ts": 0.25, "mode": "scan"},
-                {"event": "run_end", "ts": 0.3}]
-        spans = [e for e in wall_track(recs) if e["ph"] == "X"]
-        assert [s["dur"] for s in spans] == [pytest.approx(0.25e6),
-                                             pytest.approx(0.05e6)]
