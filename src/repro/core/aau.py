@@ -327,10 +327,42 @@ def select_pool_batch_at(pools: Pytree, widx: jax.Array,
     The sparse sibling of :func:`select_pool_batch`: instead of every
     worker's current batch it gathers only the A active lanes' batches —
     pools stay untouched for the other n − A workers.
+
+    Each floating batch is multiplied by :func:`pool_lane_factor`, which is
+    exactly 1 on every lane, so the batch is the gathered values bit for
+    bit.  Without it the TPU compiler, whose DEFAULT-precision matmuls read
+    the batch as bf16, rewrites ``convert(gather(pool))`` as
+    ``gather(convert(pool))`` and hoists the convert of the loop-invariant
+    pool out of the scan: every block call would convert the whole
+    ``(n, pool, B, d)`` pool to bf16 while its steps read only a few A-lane
+    batches.  A factor the compiler cannot prove constant keeps the gather
+    on the pool as stored and the cast inside each step's own matmuls
+    (``tests/test_tpu_compile.py`` checks the compiled program).  The dense
+    :func:`select_pool_batch` does without it: every dense step reads all n
+    workers' batches, so one hoisted convert a call is cheaper than one of
+    n batches a step.
     """
     def sel(pool):
-        return pool[widx, ptra % pool.shape[1]]
+        idx = ptra % pool.shape[1]
+        batch = pool[widx, idx]
+        if not jnp.issubdtype(batch.dtype, jnp.floating):
+            return batch
+        # × 1: without it the TPU compiler converts the whole pool to bf16
+        # on every block call
+        one = pool_lane_factor(idx).astype(batch.dtype)
+        return batch * one.reshape((-1,) + (1,) * (batch.ndim - 1))
     return jax.tree.map(sel, pools)
+
+
+def pool_lane_factor(idx: jax.Array) -> jax.Array:
+    """``idx >= 0`` as a bool per lane: 1 for every pool index ``ptr mod pool``.
+
+    jnp's ``%`` rounds towards −∞, so a positive pool length leaves every
+    int32 ``ptr`` (negative ones included) an index in ``[0, pool)``; the
+    compiler does not fold the comparison, which is all the factor is for
+    (:func:`select_pool_batch_at`).
+    """
+    return idx >= 0
 
 
 def sparse_gossip_scan(
