@@ -151,6 +151,11 @@ class RunCounters:
     carries the counts beside the spans.  ``per_event`` mode dispatches no
     block programs (``blocks``/``rows`` stay 0); ``fused`` mode draws its
     pairs on the device, so its ``active`` lanes stay uncounted.
+
+    ``grad_evaluated`` counts the gradient lanes the programs evaluate,
+    masked and padding lanes included: n a dense scan row (padded rows
+    too) or ``per_event`` event, lanes × steps of each sparse block (its
+    padded rows skip the update), 2 a ``fused`` event.
     """
     events: int = 0       # events consumed
     blocks: int = 0       # block programs dispatched
@@ -158,6 +163,7 @@ class RunCounters:
     active: int = 0       # active lanes of the consumed events
     grad: int = 0         # gradient lanes of the consumed events
     restarts: int = 0     # restarted lanes of the consumed events
+    grad_evaluated: int = 0   # gradient lanes the programs evaluate
 
     def add(self, **counts: int) -> None:
         for k, v in counts.items():
@@ -559,13 +565,15 @@ class DecentralizedTrainer:
                                    dtype=np.int32)),
         ), log
 
-    def _launch_sparse(self, blocks: List[tuple]) -> Tuple[int, int]:
+    def _launch_sparse(self, blocks: List[tuple]) -> Tuple[int, int, int]:
         """Enqueue packed sparse blocks in order, each alone under the
-        ``dispatch:sparse_scan`` span; returns (blocks, scan rows)."""
-        rows = 0
+        ``dispatch:sparse_scan`` span; returns (blocks, scan rows, gradient
+        lanes evaluated: lanes × unpadded rows)."""
+        rows = lanes = 0
         for xs, log in blocks:
             self._log.log("block_dispatch", mode="sparse_scan", **log)
             rows += log["padded"]
+            lanes += log["events"] * log["lanes"]
             with _span("dispatch:sparse_scan"):
                 if self.telemetry:
                     (self.W, self.S, self.y, self._ptr,
@@ -576,7 +584,7 @@ class DecentralizedTrainer:
                     self.W, self.S, self.y, self._ptr = self._sparse(
                         self.W, self.S, self.y, self._ptr, self._pools,
                         *xs)
-        return len(blocks), rows
+        return len(blocks), rows, lanes
 
     def _events_per_step(self, A: int) -> int:
         """Events merged per scan step at lane width ``A`` (the blocking K).
@@ -599,11 +607,11 @@ class DecentralizedTrainer:
         return int(np.clip(64 // max(A, 1), 1, 16))
 
     def _dispatch_sparse_chunk(self, batch: SparseEventBatch, rounds: int,
-                               cap: int) -> Tuple[int, int]:
+                               cap: int) -> Tuple[int, int, int]:
         """Advance the carry through one same-bucket packed chunk.
 
         All of the chunk's blocks are packed under one ``runner:pack``
-        span, then enqueued in order.  Returns (blocks, scan rows).
+        span, then enqueued in order.  Returns ``_launch_sparse``'s counts.
         """
         with _span("runner:pack"):
             blocks = self._pack_sparse_chunk(batch, rounds, cap)
@@ -666,7 +674,7 @@ class DecentralizedTrainer:
                    // (buckets[b] * buckets[b]))
 
     def _dispatch_bucketed(self, bucketed: BucketedSparseEventBatch,
-                           rounds: int, target: int) -> Tuple[int, int]:
+                           rounds: int, target: int) -> Tuple[int, int, int]:
         """Advance the carry through a bucketed block, in stream order.
 
         State updates are sequential, so buckets are *not* replayed whole:
@@ -679,7 +687,7 @@ class DecentralizedTrainer:
         order — the bucketed path's results are bit-exact against the dense
         scan — while a typical DSGD-AAU event pays for ~16 lanes instead
         of n.  Every segment is packed under one ``runner:pack`` span before
-        the first block is enqueued.  Returns (blocks, scan rows).
+        the first block is enqueued.  Returns ``_launch_sparse``'s counts.
         """
         with _span("runner:pack"):
             blocks = [
@@ -930,7 +938,8 @@ class DecentralizedTrainer:
             k, t = ev.k, ev.time
             self.counters.add(events=1, active=len(ev.workers),
                               grad=ev.grad_lanes.sum(),
-                              restarts=ev.restart_lanes.sum())
+                              restarts=ev.restart_lanes.sum(),
+                              grad_evaluated=self.n)
             comm += ev.param_copies_sent
             active_sizes.append(ev.n_active)
             if self.trace:
@@ -1021,7 +1030,8 @@ class DecentralizedTrainer:
                 events=batch.E, blocks=1, rows=rows,
                 active=sum(len(ev.workers) for ev in buf),
                 grad=batch.grad_workers.sum(),
-                restarts=batch.restart_workers.sum())
+                restarts=batch.restart_workers.sum(),
+                grad_evaluated=rows * self.n)
             rounds += len(buf)
             buf = []
             if rounds % eval_every == 0:
@@ -1104,17 +1114,18 @@ class DecentralizedTrainer:
             if isinstance(chunk, BucketedSparseEventBatch):
                 if self.telemetry:
                     self._accum_occupancy(chunk.occupancy())
-                blocks, rows = self._dispatch_bucketed(chunk, rounds, target)
+                blocks, rows, lanes = self._dispatch_bucketed(
+                    chunk, rounds, target)
             else:
                 if self.telemetry:
                     self._accum_occupancy([{
                         "A": int(chunk.A), "events": int(chunk.E),
                         "lane_fill": float(chunk.n_workers.sum())
                         / max(chunk.E * chunk.A, 1)}])
-                blocks, rows = self._dispatch_sparse_chunk(chunk, rounds,
-                                                           target)
+                blocks, rows, lanes = self._dispatch_sparse_chunk(
+                    chunk, rounds, target)
             self.counters.add(events=chunk.E, blocks=blocks, rows=rows,
-                              **_lane_counts(chunk))
+                              grad_evaluated=lanes, **_lane_counts(chunk))
             rounds += chunk.E
             if rounds % eval_every == 0:
                 eval_buf = self._record_eval(eval_buf, len(meta))
@@ -1199,9 +1210,9 @@ class DecentralizedTrainer:
             self._log.log("block_dispatch", mode="fused", events=E,
                           rounds=rounds)
             # one finisher per event: its lane is the gradient and the
-            # restart lane
+            # restart lane; the block evaluates both lanes of the pair
             self.counters.add(events=E, blocks=1, rows=E, grad=E,
-                              restarts=E)
+                              restarts=E, grad_evaluated=2 * E)
             with _span("dispatch:fused"):
                 (self.W, self.S, self.y, self._ptr, times, lock_free,
                  comm_dev), ys = self._fused(

@@ -6,6 +6,9 @@ spans and the ``dispatch:*`` enqueue spans; the uploads of a block's
 arguments sit under ``runner:pack``, never under ``dispatch:*``; and the
 ``runner:run`` span carries the run's :class:`RunCounters` as metadata.
 """
+import dataclasses
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -134,10 +137,70 @@ def test_warmup_counts_nothing():
 
 def test_since_is_a_difference():
     a = RunCounters(events=3, blocks=1, rows=8, active=6, grad=3,
-                    restarts=3)
+                    restarts=3, grad_evaluated=128)
     b = RunCounters(events=5, blocks=2, rows=16, active=10, grad=5,
-                    restarts=4)
+                    restarts=4, grad_evaluated=256)
     assert b.since(a) == {"events": 2, "blocks": 1, "rows": 8, "active": 4,
-                          "grad": 2, "restarts": 1}
+                          "grad": 2, "restarts": 1, "grad_evaluated": 128}
     a.add(events=np.int64(2), blocks=1)
     assert (a.events, a.blocks) == (5, 2)
+
+
+class _DispatchSink:
+    """File-like run log keeping the ``block_dispatch`` records."""
+
+    def __init__(self):
+        self.records = []
+
+    def write(self, line):
+        if '"block_dispatch"' in line:
+            self.records.append(json.loads(line))
+
+    def flush(self):
+        pass
+
+
+@pytest.mark.parametrize("alg", ["dsgd_aau", "dsgd_sync"])
+def test_dense_scan_evaluates_n_lanes_a_row(alg):
+    sink = _DispatchSink()
+    tr = _trainer(alg, "scan", run_log=sink)
+    tr.warmup()
+    sink.records.clear()
+    before = dataclasses.replace(tr.counters)
+    tr.run(max_events=20, eval_every=10)
+    got = tr.counters.since(before)
+    rows = sum(r["padded"] for r in sink.records)
+    assert got["rows"] == rows
+    assert got["grad_evaluated"] == N * rows
+    assert got["grad"] <= got["grad_evaluated"]
+
+
+@pytest.mark.parametrize("alg", ["dsgd_aau", "ad_psgd"])
+def test_sparse_blocks_evaluate_rung_lanes_a_merged_step(alg):
+    sink = _DispatchSink()
+    tr = _trainer(alg, "sparse_scan", run_log=sink)
+    tr.warmup()
+    sink.records.clear()
+    before = dataclasses.replace(tr.counters)
+    tr.run(max_events=40, eval_every=20)
+    got = tr.counters.since(before)
+    merged = [r for r in sink.records if r["merged"]]
+    assert merged, "the stream merged no events"
+    for r in merged:
+        # a merged step is K events of the rung's width A, packed together
+        rung = max(A for A in tr.scheduler.active_buckets() if A <= r["lanes"])
+        assert r["lanes"] == rung * tr._events_per_step(rung)
+    # padded rows skip the update: only the block's real steps count
+    assert got["grad_evaluated"] == sum(r["events"] * r["lanes"]
+                                        for r in sink.records)
+    assert got["grad"] <= got["grad_evaluated"] <= sum(
+        r["padded"] * r["lanes"] for r in sink.records)
+
+
+def test_per_event_and_fused_count_their_lanes():
+    tr = _trainer("dsgd_aau", "per_event")
+    tr.run(max_events=6, eval_every=3)
+    assert tr.counters.grad_evaluated == 6 * N
+    tr = _trainer("ad_psgd", "fused")
+    tr.run(max_events=8, eval_every=4)
+    assert tr.counters.grad_evaluated == 2 * 8
