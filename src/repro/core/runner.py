@@ -7,7 +7,8 @@ communication, reproducing the paper's Figures 3–5 measurement protocol.
 
 Execution model — block-compiled, mode chosen automatically by default
 (``mode="auto"`` resolves to the dense ``scan`` or the active-set
-``sparse_scan`` via :func:`choose_mode`'s recorded crossover heuristic):
+``sparse_scan`` via :func:`choose_mode`, from n, the scheduler's lane
+ladder and the gradient's FLOPs per byte of a worker's rows):
 
 - The event stream is packed ``block_size`` events at a time into
   :class:`~repro.core.scheduler.EventBatch` stacked arrays and replayed on
@@ -70,26 +71,53 @@ from repro.obs.trace import TraceRecorder, drain_fused_payload
 from repro.utils.tree import tree_size, tree_stack
 
 
+# Gradient FLOPs a lane per byte of the lane's W and S rows above which the
+# gradient, not the row traffic, bounds a lane: v5e's ridge, 197 TFLOP/s ÷
+# 819 GB/s ≈ 240.  The paper's 2-NN reads ≈17 (row-bound: a masked lane of
+# the dense scan costs little beside the gathers it saves); ResNet-18 ≈1,040
+# (compute-bound: every masked or padded lane costs a whole gradient).
+COMPUTE_BOUND_FLOPS_PER_BYTE = 240.0
+
+
 def choose_mode(n: int, buckets: Tuple[int, ...],
-                global_events: bool = False) -> str:
+                global_events: bool = False,
+                flops_per_byte: Optional[float] = None) -> str:
     """``mode="auto"``'s dispatch decision: dense ``scan`` vs ``sparse_scan``.
 
-    The sparse path wins when gathering the ladder's typical A lanes beats
-    touching all n rows; at small n the dense scan's single fixed-shape
-    block both avoids the gather/scatter overhead and compiles once.  The
-    recorded BENCH_event_stream rows put the crossover consistently around
-    ``n ≈ 4·A`` for the narrowest rung (AD-PSGD at N=16 ran the sparse path
-    at 0.52× the dense scan; DSGD-AAU at N=64, whose first rung is 16, at
-    0.91×; both cross above 1 at the next measured scale), with a floor of
-    n=16 below which nothing beats the dense scan.  Barrier schedulers
-    (``global_events``) always take the dense scan — every event touches
-    all n workers, so sparse gathering is pure overhead.
+    - Barrier streams (``global_events``) take the dense scan: every event
+      touches all n workers, so gathering them is pure overhead.
+    - A compute-bound gradient (``flops_per_byte``, the gradient's FLOPs a
+      lane over the bytes of one worker's W and S rows, above
+      ``COMPUTE_BOUND_FLOPS_PER_BYTE``) takes the sparse scan at any n: the
+      dense scan evaluates all n lanes every event, the sparse one only the
+      lanes of its rung.
+    - Otherwise a lane costs about its rows, and the dense scan's single
+      fixed-shape block wins until n outgrows the narrowest rung: the
+      sparse scan is taken above ``max(16, 4 · buckets[0])`` workers.
     """
     if global_events:
         return "scan"
+    if flops_per_byte is not None \
+            and flops_per_byte > COMPUTE_BOUND_FLOPS_PER_BYTE:
+        return "sparse_scan"
     if n <= max(16, 4 * buckets[0]):
         return "scan"
     return "sparse_scan"
+
+
+def grad_cost(loss_fn: Callable, row, batch) -> Tuple[float, int]:
+    """``(F, R)`` of one gradient lane: F the FLOPs of ``jax.grad(loss_fn)``
+    at one worker's parameter row and batch (pytrees of arrays or shapes),
+    as XLA's cost analysis counts them; R the bytes of that worker's W row
+    plus its S row.  A backend that hosts XLA itself analyses the lowered
+    module; a plugin backend (the TPU) analyses compiled modules only."""
+    lowered = jax.jit(jax.grad(loss_fn)).lower(row, batch)
+    cost = lowered.cost_analysis()
+    if cost is None:
+        cost = lowered.compile().cost_analysis()
+    row_bytes = sum(x.size * jnp.dtype(x.dtype).itemsize
+                    for x in jax.tree.leaves(row))
+    return float(cost["flops"]), 2 * int(row_bytes)
 
 
 @dataclasses.dataclass
@@ -205,8 +233,9 @@ class DecentralizedTrainer:
         use_kernel: bool = False,
         same_init: bool = True,
         mode: str = "auto",                 # "auto" (choose_mode picks scan
-                                            # vs sparse_scan from n and the
-                                            # scheduler's lane ladder) |
+                                            # vs sparse_scan from n, the
+                                            # scheduler's lane ladder and
+                                            # the gradient's cost) |
                                             # "scan" | "sparse_scan" |
                                             # "per_event" | "fused"
         block_size: int = 32,               # events per compiled scan call
@@ -221,7 +250,8 @@ class DecentralizedTrainer:
                                             # sparse path: merge up to K
                                             # conflict-free events per scan
                                             # step (None = auto per bucket,
-                                            # ~64 lanes/step; 1 disables)
+                                            # min(64, n) lanes/step; 1
+                                            # disables)
         native_generation: bool = True,     # sparse path: schedulers with an
                                             # array-native generator fill the
                                             # packed chunks directly (bit-
@@ -254,9 +284,6 @@ class DecentralizedTrainer:
         self.dtype = jnp.dtype(dtype)
         if not jnp.issubdtype(self.dtype, jnp.floating):
             raise ValueError(f"dtype policy must be a float dtype, got {dtype!r}")
-        if mode == "auto":
-            mode = choose_mode(scheduler.n, scheduler.active_buckets(),
-                               scheduler.global_events)
         if mode == "fused" and not (hasattr(scheduler, "fused_spec")
                                     and scheduler.fused_supported()):
             raise ValueError(
@@ -276,7 +303,6 @@ class DecentralizedTrainer:
         self.eval_batch = eval_batch
         self.eta0, self.eta_decay, self.eta_decay_every = eta0, eta_decay, eta_decay_every
         self.use_kernel = use_kernel
-        self.mode = mode
         self.block_size = max(1, block_size)
         self.batch_pool = batch_pool if batch_pool is None else max(1, batch_pool)
         self.events_per_step = events_per_step
@@ -303,6 +329,20 @@ class DecentralizedTrainer:
         self.S = self.W
         self.y = jnp.ones((self.n,), dtype=jnp.float32)
         self.param_count = tree_size(params[0])
+        # (F, R) of one gradient lane (grad_cost), read under mode="auto"
+        # for streams whose path it can decide
+        self.grad_cost = None
+        if mode == "auto":
+            if not scheduler.global_events:
+                self.grad_cost = grad_cost(
+                    loss_fn, jax.eval_shape(self._cast, params[0]),
+                    jax.eval_shape(self._cast, worker_batch_fn(0, 0)))
+            mode = choose_mode(
+                self.n, scheduler.active_buckets(), scheduler.global_events,
+                None if self.grad_cost is None
+                else self.grad_cost[0] / self.grad_cost[1])
+        self.mode = mode
+        self._log_lane_policy()
         self._eval = jax.jit(self.eval_fn)
         # Per-mode state built lazily on first use (avoids tracing both paths).
         self._step = None           # per-event jitted update
@@ -324,6 +364,23 @@ class DecentralizedTrainer:
         self._trace = None          # TraceRecorder (host-side buffers)
         self.last_trace = None      # finalized Trace of the latest run
         self.counters = RunCounters()
+
+    def _log_lane_policy(self) -> None:
+        """One ``lane_policy`` line on the run log: the resolved mode, the
+        gradient's cost that chose it, and the gradient lanes a block step
+        evaluates at each rung (n for the dense scan, K·A for a sparse rung
+        that merges K events a step)."""
+        F, R = self.grad_cost or (None, None)
+        if self.mode == "sparse_scan":
+            budget = [[A, A * self._events_per_step(A)]
+                      for A in self.scheduler.active_buckets()]
+        elif self.mode == "scan":
+            budget = [[self.n, self.n]]
+        else:
+            budget = None
+        self._log.log("lane_policy", mode=self.mode, grad_flops=F,
+                      row_bytes=R, flops_per_byte=None if F is None else F / R,
+                      lanes_per_step=budget)
 
     def _cast(self, tree):
         """Apply the worker-state dtype policy to a pytree's float leaves."""
@@ -589,22 +646,17 @@ class DecentralizedTrainer:
     def _events_per_step(self, A: int) -> int:
         """Events merged per scan step at lane width ``A`` (the blocking K).
 
-        The per-scan-step dispatch cost (~100 µs on this CPU backend,
-        measured in BENCH_event_stream) is independent of the step's lane
-        count, so folding a run of conflict-free events into one K·A-lane
-        step amortizes it group-size-fold.  K·A is a *lane budget* —
-        ``merge_event_groups`` packs members compactly, so low-fill streams
-        fit more than K events per step.  The auto policy targets ~64 lanes
-        per step — enough to amortize, small enough that one conflicting
-        event doesn't truncate groups often: A=2 pair events merge
-        16-deep, DSGD-AAU's typical A=16 rung packs ~10 of its ~5-worker
-        cliques per step, and A≥64 rungs stay unmerged (at budgets near n,
-        conflicts are certain and the padded lanes cost more than the
-        amortized thunk).
+        K·A is the step's *lane budget*: ``merge_event_groups`` packs
+        conflict-free events compactly into it, and every lane of it is a
+        gradient the step evaluates, real or padding.  The budget is
+        min(64, n) lanes: enough to amortize a scan step's fixed cost over
+        several narrow events, and never wider than the n workers a step
+        could touch, so a rung as wide as n stays unmerged.  K is clipped
+        to [1, 16]; ``events_per_step`` overrides it.
         """
         if self.events_per_step is not None:
             return max(1, int(self.events_per_step))
-        return int(np.clip(64 // max(A, 1), 1, 16))
+        return int(np.clip(min(64, self.n) // max(A, 1), 1, 16))
 
     def _dispatch_sparse_chunk(self, batch: SparseEventBatch, rounds: int,
                                cap: int) -> Tuple[int, int, int]:

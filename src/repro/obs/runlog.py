@@ -8,6 +8,10 @@ the hot dispatch loops can log unconditionally.
 
 Events the trainer emits (the log schema, also documented in README):
 
+``lane_policy``    once per trainer: resolved mode, the gradient's FLOPs a
+                   lane and bytes of a worker's W and S rows (``mode="auto"``
+                   only), and the gradient lanes a block step evaluates
+                   at each rung
 ``run_start``      n, mode, algorithm-ish metadata the caller passes
 ``block_dispatch`` mode, events, rounds — one per compiled block launch
 ``compile``        key — first-time build of a jitted block (cache miss)

@@ -24,7 +24,8 @@ import pytest
 
 from repro.core import topology
 from repro.core.baselines import make_scheduler
-from repro.core.runner import DecentralizedTrainer, choose_mode
+from repro.core.runner import (COMPUTE_BOUND_FLOPS_PER_BYTE,
+                               DecentralizedTrainer, choose_mode)
 from repro.core.scheduler import (BucketedSparseEventBatch, PackedEventStream,
                                   SparseEventBatch, merge_event_groups)
 from repro.core.straggler import StragglerModel, TimeSampler
@@ -183,6 +184,26 @@ class TestChooseMode:
         assert choose_mode(64, (16, 32, 64)) == "scan"
         assert choose_mode(256, (16, 64, 256)) == "sparse_scan"
         assert choose_mode(1024, (2,), global_events=True) == "scan"
+
+    # F/R: the gradient's FLOPs a lane per byte of a worker's W and S rows
+    # (the paper's 2-NN reads ≈17, ResNet-18 ≈1,040)
+    @pytest.mark.parametrize("n, ladder, global_events, flops_per_byte, want", [
+        (128, (16, 64, 128), False, 24.0, "sparse_scan"),
+        (256, (16, 64, 256), False, 24.0, "sparse_scan"),
+        (128, (2,), False, 24.0, "sparse_scan"),
+        (32, (16, 32), False, 24.0, "scan"),
+        (32, (16, 32), False, 1000.0, "sparse_scan"),
+        (16, (2,), False, 1000.0, "sparse_scan"),
+        (128, (128,), True, 24.0, "scan"),
+        (32, (32,), True, 1000.0, "scan"),
+    ])
+    def test_payload_cost(self, n, ladder, global_events, flops_per_byte,
+                          want):
+        got = choose_mode(n, ladder, global_events, flops_per_byte)
+        assert got == want
+        if flops_per_byte < COMPUTE_BOUND_FLOPS_PER_BYTE or global_events:
+            # below the ridge, and for barriers, the answer without the cost
+            assert got == choose_mode(n, ladder, global_events)
 
     def test_auto_resolves_at_construction(self):
         tr = _trainer("ad_psgd", "auto", block_size=8, batch_pool=48)

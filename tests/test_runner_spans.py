@@ -32,11 +32,11 @@ def loss_fn(params, batch):
     return -jnp.mean(jnp.take_along_axis(logp, batch["y"][:, None], axis=1))
 
 
-def _trainer(alg, mode, **kw):
+def _trainer(alg, mode, sched_kw=None, **kw):
     g = topology.erdos_renyi(N, 0.4, seed=3)
     sm = StragglerModel(n=N, straggler_prob=0.2, slowdown=6.0, seed=0)
     return DecentralizedTrainer(
-        make_scheduler(alg, g, sm), loss_fn,
+        make_scheduler(alg, g, sm, **(sched_kw or {})), loss_fn,
         lambda k: {"w": jax.random.normal(k, (16, 4)) * 0.1},
         lambda w, s: DATA.batch(w, s, batch_size=8), DATA.eval_batch(64),
         mode=mode, block_size=8, **kw)
@@ -178,7 +178,10 @@ def test_dense_scan_evaluates_n_lanes_a_row(alg):
 @pytest.mark.parametrize("alg", ["dsgd_aau", "ad_psgd"])
 def test_sparse_blocks_evaluate_rung_lanes_a_merged_step(alg):
     sink = _DispatchSink()
-    tr = _trainer(alg, "sparse_scan", run_log=sink)
+    # DSGD-AAU's default ladder at N=16 is one rung as wide as n, which
+    # merges nothing: a narrower first rung does
+    tr = _trainer(alg, "sparse_scan", run_log=sink,
+                  sched_kw={"buckets": (4, N)} if alg == "dsgd_aau" else None)
     tr.warmup()
     sink.records.clear()
     before = dataclasses.replace(tr.counters)
@@ -186,15 +189,33 @@ def test_sparse_blocks_evaluate_rung_lanes_a_merged_step(alg):
     got = tr.counters.since(before)
     merged = [r for r in sink.records if r["merged"]]
     assert merged, "the stream merged no events"
+    budgets = {A * tr._events_per_step(A)
+               for A in tr.scheduler.active_buckets()
+               if tr._events_per_step(A) > 1}
     for r in merged:
         # a merged step is K events of the rung's width A, packed together
-        rung = max(A for A in tr.scheduler.active_buckets() if A <= r["lanes"])
-        assert r["lanes"] == rung * tr._events_per_step(rung)
+        assert r["lanes"] in budgets
     # padded rows skip the update: only the block's real steps count
     assert got["grad_evaluated"] == sum(r["events"] * r["lanes"]
                                         for r in sink.records)
     assert got["grad"] <= got["grad_evaluated"] <= sum(
         r["padded"] * r["lanes"] for r in sink.records)
+
+
+@pytest.mark.parametrize("n", [32, 128, 256])
+def test_merge_budget_is_no_wider_than_n(n):
+    g = topology.erdos_renyi(n, 0.15, seed=1)
+    sm = StragglerModel(n=n, straggler_prob=0.2, slowdown=6.0, seed=0)
+    tr = DecentralizedTrainer(
+        make_scheduler("dsgd_aau", g, sm), loss_fn,
+        lambda k: {"w": jnp.zeros((16, 4))},
+        lambda w, s: DATA.batch(0, s, batch_size=8), DATA.eval_batch(64),
+        mode="sparse_scan")
+    for A in tr.scheduler.active_buckets() + (2,):     # AAU rungs, pairs
+        K = tr._events_per_step(A)
+        assert K * A <= max(A, n)
+        if n >= 64:
+            assert K == int(np.clip(64 // A, 1, 16))
 
 
 def test_per_event_and_fused_count_their_lanes():
