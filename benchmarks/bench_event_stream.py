@@ -27,35 +27,23 @@ rows record ``gen_horizon_eps: null`` — the number-or-null metric schema
 enforced by ``common.write_bench_json``, which also normalizes the legacy
 ``"unsupported"`` string older recordings carried).
 
-Two further columns record the device-resident streaming pipeline:
+``e2e_eps`` times the sparse path at its *defaults* — array-native
+packed generation plus the event-blocked scan (K conflict-free events
+merged per ``lax.scan`` step) — generation and consumption together.
+``sparse_eps`` stays measured with ``events_per_step=1`` (one event per
+scan step).
 
-- ``e2e_eps``: the sparse path at its *defaults* — array-native packed
-  generation plus the event-blocked scan (K conflict-free events merged
-  per ``lax.scan`` step) — timed generation+consumption together.
-  ``sparse_eps`` stays measured with ``native_generation=False,
-  events_per_step=1`` so it remains comparable with earlier recordings of
-  the one-event-per-step object path.
-- ``fused_eps``: ``mode="fused"`` for the single-edge schedulers — event
-  generation and consumption fused into one compiled scan, host work
-  reduced to two vectorized RNG draws per block (a different-but-
-  deterministic RNG-order realization; see core/fused.py).
-
-Telemetry overhead (``repro.obs``): ``fused_tel_eps`` re-times the fused
-path with ``telemetry=True`` (the scan streams each event's identity out
-as extra outputs; the run folds once at drain — ``fused_metrics_fold``)
-and ``telemetry_overhead`` records the with/without ratio; ``--smoke``
-asserts it stays under 1.10 — the device-resident-telemetry contract is
-that counters never cost a host sync or per-event scatter on the fused
-path.  ``e2e_tel_eps`` records the same pair for the DSGD-AAU sparse
-stream (the bucketed ladder, worst case for extra carries).
-
-Trace overhead (``repro.obs.trace``): ``e2e_trace_eps`` /
-``trace_overhead`` re-time the DSGD-AAU stream with ``trace=True`` —
-host-side event-identity recording per block plus the end-of-run
-wait-blame attribution — and ``--smoke`` asserts the same < 1.10x bound
-(tracing must never sync mid-run); ``fused_trace_eps`` records the fused
-pair, whose whole-run payload is fetched with a single ``jax.device_get``
-at drain.
+Observability overhead, measured on the sparse stream of the AD-PSGD pair
+scheduler and of DSGD-AAU (the bucketed ladder, worst case for extra
+carries): ``e2e_tel_eps`` / ``e2e_tel_overhead`` re-time it with
+``telemetry=True`` (``repro.obs``: device-resident counters drained once
+per run), ``e2e_trace_eps`` / ``trace_overhead`` with ``trace=True``
+(``repro.obs.trace``: host-side event-identity recording per block plus
+the end-of-run wait-blame attribution).  ``--smoke`` asserts the trace
+ratio stays under 1.10 on both streams: tracing must never sync mid-run.
+The telemetry ratio is recorded, not asserted: the sparse block folds its
+counters every scan step, which at this bench's 16-wide payload costs
+about a fifth of a step on the CPU.
 
   python -m benchmarks.bench_event_stream [--paper-scale] [--xl] [--smoke]
       # writes BENCH_event_stream.json
@@ -91,9 +79,7 @@ D_IN, D_H, BATCH = 16, 16, 4
 PER_EVENT_MAX_N = 64     # legacy interpreter is noise above this scale
 SCAN_MAX_N = 256         # dense O(n²·D) mix: wall-clock filler above this
 HORIZON_ALGS = ("ad_psgd", "agp")   # single-edge scheds accept horizon=
-FUSED_ALGS = ("ad_psgd",)           # single-edge member of ALGS (agp's
-                                    # fused path is the same code; its
-                                    # equivalence lives in the test suite)
+OVERHEAD_ALGS = ("ad_psgd", "dsgd_aau")  # observability overhead rows
 
 _JSON_PATH = os.path.join(os.path.dirname(__file__), "..",
                           "BENCH_event_stream.json")
@@ -132,7 +118,7 @@ def _make_trainer(alg: str, mode: str, n: int, block_size: int,
     # bounds used here (~81 at N=16); bigger pools measurably slow the
     # per-step gather on CPU, which would pollute the dispatch comparison.
     kw = ({"block_size": block_size, "batch_pool": 96}
-          if mode in ("scan", "sparse_scan", "fused") else {})
+          if mode in ("scan", "sparse_scan") else {})
     kw.update(trainer_kw or {})
     return DecentralizedTrainer(
         _make_sched(alg, n, **sched_kw), _loss, _init,
@@ -206,6 +192,29 @@ def _bucket_occupancy(alg: str, n: int, events: int):
     return list(map(int, buckets)), occ.occupancy()
 
 
+def _overhead_rows(alg: str, n: int, events: int, smoke: bool):
+    """Telemetry and trace cost on the sparse stream, each measured
+    interleaved against its own base (a separately-timed pair under host
+    generation noise can fake a large ratio).  The telemetry ratio is
+    recorded; the trace ratio is asserted < 1.10 under ``--smoke`` (tracing
+    must never sync mid-run) on a longer run — a 64-event run is all fixed
+    cost and would fake any ratio."""
+    base, tel = _flag_overhead_pair(alg, "sparse_scan", n, events,
+                                    min(BLOCK_SIZE, events),
+                                    repeats=2 if smoke else 3)
+    trace_events = max(events, 2048) if smoke else events
+    trace_base, trace = _flag_overhead_pair(
+        alg, "sparse_scan", n, trace_events, min(BLOCK_SIZE, trace_events),
+        flag="trace", repeats=3)
+    row = {"e2e_tel_eps": tel, "e2e_tel_overhead": base / tel,
+           "e2e_trace_eps": trace, "trace_overhead": trace_base / trace}
+    if smoke:
+        assert row["trace_overhead"] < 1.10, (
+            f"virtual-time tracing cost {row['trace_overhead']:.3f}x "
+            f"on the streaming path (contract: < 1.10x)")
+    return row
+
+
 def run(paper_scale: bool = False, smoke: bool = False, xl: bool = False):
     sizes = bench_sizes(paper_scale, smoke, xl)
     results = []
@@ -214,11 +223,10 @@ def run(paper_scale: bool = False, smoke: bool = False, xl: bool = False):
         block = min(BLOCK_SIZE, events)
         gen = _generation_events_per_sec(alg, n, events)
         buckets, occupancy = _bucket_occupancy(alg, n, events)
-        # PR6-comparable configuration: object-path generation, one event
-        # per scan step — the pre-streaming sparse path.
+        # one event per scan step: the unmerged sparse path
         sparse = _events_per_sec(
             alg, "sparse_scan", n, events, block,
-            trainer_kw=dict(native_generation=False, events_per_step=1))
+            trainer_kw=dict(events_per_step=1))
         # The streaming defaults: native packed generation + event-blocked
         # scan, generation and consumption timed together.
         e2e = _events_per_sec(alg, "sparse_scan", n, events, block)
@@ -239,48 +247,6 @@ def run(paper_scale: bool = False, smoke: bool = False, xl: bool = False):
             # the horizon batcher's flat pre-draw doesn't apply (null, per
             # the number-or-null metric schema; see common.write_bench_json)
             row["gen_horizon_eps"] = None
-        if alg in FUSED_ALGS:
-            # Telemetry overhead: the same fused config with a MetricsCarry
-            # of device accumulators riding the block.  Smoke asserts the
-            # < 10% contract on a longer interleaved timing so CI load
-            # drift can't fake a regression.
-            tel_events = max(events, 2048) if smoke else events
-            tel_block = min(BLOCK_SIZE, tel_events)
-            fused, fused_tel = _flag_overhead_pair(
-                alg, "fused", n, tel_events, tel_block,
-                repeats=4 if smoke else 2)
-            overhead = fused / fused_tel
-            row["fused_eps"] = fused
-            row["fused_tel_eps"] = fused_tel
-            row["telemetry_overhead"] = overhead
-            yield csv_row(f"event_stream_fused_{alg}_n{n}", 1e6 / fused,
-                          f"{fused:.0f} events/s fused gen+consume")
-            yield csv_row(f"event_stream_fused_tel_{alg}_n{n}",
-                          1e6 / fused_tel,
-                          f"{fused_tel:.0f} events/s with telemetry "
-                          f"({overhead:.3f}x overhead)")
-            if smoke:
-                assert overhead < 1.10, (
-                    f"device-resident telemetry cost {overhead:.3f}x on the "
-                    f"fused path (contract: < 1.10x)")
-            # The trace rides the same widened scan outputs and pays one
-            # jax.device_get over the whole run's payload at drain
-            # (repro.obs.trace.drain_fused_payload) — recorded so the
-            # drain-once design has a number; the asserted contract row is
-            # the streaming pair below.
-            # always >= 2048 events: the drain's fixed cost (one device_get
-            # + attribution) on a ~30 ms run otherwise reads as a fake
-            # 10-20% "overhead"
-            fused_tr_events = max(events, 2048)
-            fused_tr_base, fused_trace = _flag_overhead_pair(
-                alg, "fused", n, fused_tr_events,
-                min(BLOCK_SIZE, fused_tr_events), flag="trace", repeats=4)
-            row["fused_trace_eps"] = fused_trace
-            row["fused_trace_overhead"] = fused_tr_base / fused_trace
-            yield csv_row(f"event_stream_fused_trace_{alg}_n{n}",
-                          1e6 / fused_trace,
-                          f"{fused_trace:.0f} events/s with trace "
-                          f"({fused_tr_base / fused_trace:.3f}x overhead)")
         if n <= PER_EVENT_MAX_N:
             per_event = _events_per_sec(alg, "per_event", n, events, block)
             row["per_event_eps"] = per_event
@@ -295,10 +261,10 @@ def run(paper_scale: bool = False, smoke: bool = False, xl: bool = False):
         if len(buckets) > 1 and n <= SCAN_MAX_N:
             # the pre-ladder sparse path: every event padded to A=n.  Kept
             # in the artifact so the bucketing win is a recorded number
-            # (measured at the same PR6-comparable settings as sparse_eps).
+            # (measured at the same settings as sparse_eps).
             static = _events_per_sec(
                 alg, "sparse_scan", n, events, block,
-                trainer_kw=dict(native_generation=False, events_per_step=1),
+                trainer_kw=dict(events_per_step=1),
                 buckets=(n,))
             row["sparse_static_eps"] = static
             row["bucket_speedup"] = sparse / static
@@ -312,41 +278,16 @@ def run(paper_scale: bool = False, smoke: bool = False, xl: bool = False):
         yield csv_row(f"event_stream_e2e_{alg}_n{n}", 1e6 / e2e,
                       f"{e2e:.0f} events/s streaming defaults "
                       f"({e2e / sparse:.1f}x vs one-event-per-step)")
-        if alg == "dsgd_aau":
-            # sparse-path telemetry cost on the bucketed ladder (the most
-            # carries per event of any mode); recorded, not asserted — the
-            # contract row is the fused pair above.  Measured interleaved
-            # (its own base, not e2e_eps: a separately-timed pair under
-            # host generation noise can fake a large ratio).
-            e2e_base, e2e_tel = _flag_overhead_pair(
-                alg, "sparse_scan", n, events, block,
-                repeats=2 if smoke else 3)
-            row["e2e_tel_eps"] = e2e_tel
-            row["e2e_tel_overhead"] = e2e_base / e2e_tel
-            yield csv_row(f"event_stream_e2e_tel_{alg}_n{n}", 1e6 / e2e_tel,
-                          f"{e2e_tel:.0f} events/s streaming with telemetry "
-                          f"({e2e_base / e2e_tel:.3f}x overhead)")
-            # Trace cost on the same worst-case stream: host-side identity
-            # recording per block plus the end-of-run wait-blame pass
-            # (repro.obs.critical_path) — the contract is that tracing
-            # never syncs mid-run, so the asserted bound matches the
-            # telemetry one.  Longer runs in smoke: a 64-event run is all
-            # fixed cost and would fake any ratio.
-            trace_events = max(events, 2048) if smoke else events
-            trace_block = min(BLOCK_SIZE, trace_events)
-            trace_base, e2e_trace = _flag_overhead_pair(
-                alg, "sparse_scan", n, trace_events, trace_block,
-                flag="trace", repeats=3)
-            row["e2e_trace_eps"] = e2e_trace
-            row["trace_overhead"] = trace_base / e2e_trace
+        if alg in OVERHEAD_ALGS:
+            row.update(_overhead_rows(alg, n, events, smoke))
+            yield csv_row(f"event_stream_e2e_tel_{alg}_n{n}",
+                          1e6 / row["e2e_tel_eps"],
+                          f"{row['e2e_tel_eps']:.0f} events/s streaming with "
+                          f"telemetry ({row['e2e_tel_overhead']:.3f}x overhead)")
             yield csv_row(f"event_stream_e2e_trace_{alg}_n{n}",
-                          1e6 / e2e_trace,
-                          f"{e2e_trace:.0f} events/s streaming with trace "
-                          f"({trace_base / e2e_trace:.3f}x overhead)")
-            if smoke:
-                assert row["trace_overhead"] < 1.10, (
-                    f"virtual-time tracing cost {row['trace_overhead']:.3f}x "
-                    f"on the streaming path (contract: < 1.10x)")
+                          1e6 / row["e2e_trace_eps"],
+                          f"{row['e2e_trace_eps']:.0f} events/s streaming "
+                          f"with trace ({row['trace_overhead']:.3f}x overhead)")
         results.append(row)
     payload = {
         "bench": "event_stream",

@@ -41,8 +41,8 @@ MODULES = ("convergence", "walltime", "speedup", "communication",
 
 # Hard-gate metrics: the recorded throughputs each PR's perf story rests
 # on.  Everything else (overheads, speedup ratios, occupancy) only warns.
-PINNED_METRICS = ("gen_eps", "sparse_eps", "e2e_eps", "fused_eps",
-                  "scan_eps", "per_event_eps")
+PINNED_METRICS = ("gen_eps", "sparse_eps", "e2e_eps", "scan_eps",
+                  "per_event_eps")
 WARN_AT, FAIL_AT = 0.10, 0.30
 
 # Row-identity fields, in display order; whatever subset a row carries

@@ -13,7 +13,7 @@ below the initial loss.
 
   A  dsgd_aau   mode="sparse_scan"  bucketed ladder, sparse_gossip kernels
   B  dsgd_sync  mode="scan"         dense masked_gossip_mix kernel
-  C  ad_psgd    mode="fused"        device-generated pairs, sparse kernels
+  C  ad_psgd    mode="sparse_scan"  merged single-rung pairs, sparse_gossip
 
 ``--four-chips`` runs the ``shard_map`` ring gossip with one worker per chip
 against the dense mixing on one device, then a few steps of
@@ -57,10 +57,10 @@ RING_BOUND = 1e-5
 
 PHASES = {"A": ("dsgd_aau", "sparse_scan"),
           "B": ("dsgd_sync", "scan"),
-          "C": ("ad_psgd", "fused")}
+          "C": ("ad_psgd", "sparse_scan")}
 # The trainer's compiled block for each mode (the HLO check compiles it
 # again from the argument shapes of its first call).
-_BLOCK_ATTR = {"sparse_scan": "_sparse", "scan": "_scan", "fused": "_fused"}
+_BLOCK_ATTR = {"sparse_scan": "_sparse", "scan": "_scan"}
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 

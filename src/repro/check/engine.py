@@ -65,17 +65,13 @@ class CheckConfig:
             # runner-held compiled blocks: build_sparse_event_scan donates
             # the (W, S, y, ptr) carry (positions 0-3; the telemetry
             # variant also donates M at 4 but position 4 is pools in the
-            # plain variant, so only the common prefix is tracked here),
-            # build_fused_pair_scan donates (W, S, y, ptr, times,
-            # lock_free, comm) = (0,1,2,3,5,6,7).
+            # plain variant, so only the common prefix is tracked here).
             "_sparse": (0, 1, 2, 3),
-            "_fused": (0, 1, 2, 3, 5, 6, 7),
             "sparse_scatter_rows": (0,),
         }
     )
     donating_builders: Tuple[str, ...] = (
         "build_sparse_event_scan",
-        "build_fused_pair_scan",
     )
     host_sync_scopes: Tuple[str, ...] = (
         r"^_dispatch_\w+$",
@@ -83,17 +79,13 @@ class CheckConfig:
         r"^_launch_\w+$",
         r"^_run_scan$",
         r"^_run_sparse_stream$",
-        r"^_run_fused$",
         r"^_record_eval$",
-        r"^_fused_record$",
         r"^_warn_pool_wrap$",
         r"^warmup$",
-        # virtual-time tracing (repro.obs.trace): the drain is the one
-        # sanctioned host fetch per traced run; the recorders run on the
-        # hot dispatch path and must stay sync-free
+        # virtual-time tracing (repro.obs.trace): the recorders run on
+        # the hot dispatch path and, with the drain, stay sync-free
         r"^_trace_summary$",
-        r"^drain_fused_payload$",
-        r"^record_(event|events|sparse|chunk|fused)$",
+        r"^record_(event|events|sparse|chunk)$",
     )
     rng_surface_attr: str = "rng_methods"
     kernel_gate_flag: str = "use_kernel"

@@ -1,6 +1,6 @@
 """use-after-donate: donated buffers are dead after the dispatch call.
 
-``build_sparse_event_scan`` / ``build_fused_pair_scan`` compile blocks with
+``build_sparse_event_scan`` compiles blocks with
 ``donate_argnums`` over the ``(W, S, y, ptr, ...)`` carry (PR 6): XLA reuses
 the donated buffers for the outputs, so any read of the *argument* after the
 call observes freed (or silently overwritten) memory.  The sanctioned shape

@@ -14,7 +14,7 @@ steady state.  Two anti-patterns silently break that:
   ``np.array``) — a ``TypeError`` at best, a per-call retrace at worst —
   or a loop variable, which compiles once per distinct iteration value.
   Static-jitted callables are discovered locally (same module), like the
-  runner's ``jax.jit(fused_metrics_fold, static_argnums=(5,))``.
+  runner's ``jax.jit(init_metrics, static_argnums=0)``.
 """
 from __future__ import annotations
 

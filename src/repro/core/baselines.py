@@ -71,7 +71,7 @@ class _PairPackedStream(PackedEventStream):
     chunk's :class:`SparseEventBatch` arrays: no ``ScheduleEvent`` object,
     no payload tuple, no ``from_events`` re-scatter.  Bit-identical chunks
     to ``packed_stream(native=False)``, pinned by
-    tests/test_fused_stream.py.
+    tests/test_packed_stream.py.
     """
 
     def __init__(self, scheduler: "_SingleEdgeScheduler"):
@@ -174,7 +174,7 @@ class _SingleEdgeScheduler(Scheduler):
     #: pinned event stream — a draw anywhere else forks it silently.
     #: ``_PairPackedStream.next_chunk`` draws via ``sched._rng`` on the
     #: scheduler's behalf as the vectorized replay of ``_events_exact``.
-    rng_methods = ("_events_exact", "_events_horizon", "fused_draws")
+    rng_methods = ("_events_exact", "_events_horizon")
 
     def __init__(self, graph: Graph, straggler: TimeModelSpec, seed: int,
                  horizon: Optional[int] = None):
@@ -249,60 +249,6 @@ class _SingleEdgeScheduler(Scheduler):
         if self.horizon or self._needs_sorted_emission():
             return None
         return _PairPackedStream(self)
-
-    # -- fused pure-JAX generation (core/fused.py) -------------------------
-    def fused_supported(self) -> bool:
-        """Whether the on-device fused generator can replay this stream.
-
-        Requires per-worker completion-time factors that are iid draws
-        (``TimeModel.iid_horizon``): the fused scan pre-draws a flat factor
-        stream and assigns factors to workers *by event order decided on
-        device*, which is only distribution-preserving when the factor law
-        doesn't depend on which worker consumes it.  Scenario samplers with
-        worker- or history-dependent factors (diurnal) are excluded.
-        """
-        return bool(getattr(self.sampler, "iid_horizon", False))
-
-    def fused_spec(self) -> Dict[str, object]:
-        """Static device constants for the fused generator's scan body."""
-        n = self.n
-        deg = np.fromiter((len(nb) for nb in self._nbrs),
-                          dtype=np.int32, count=n)
-        width = max(1, int(deg.max(initial=1)))
-        nbr_table = np.zeros((n, width), dtype=np.int32)
-        for i, nb in enumerate(self._nbrs):
-            if len(nb):
-                nbr_table[i, :len(nb)] = nb
-        _, P1, l1, copies = self._pair_payload(0, 1)
-        _, P2, l2, _ = self._pair_payload(1, 0)
-        return dict(
-            n=n, deg=deg, nbr_table=nbr_table,
-            base=np.asarray(self.sampler.base, dtype=np.float32),
-            lock_dt=float(self.lock_time),
-            P_first=np.asarray(P1, dtype=np.float32),
-            P_second=np.asarray(P2, dtype=np.float32),
-            lane_first=np.asarray(l1, dtype=bool),
-            lane_second=np.asarray(l2, dtype=bool),
-            copies_pair=int(copies),
-        )
-
-    def fused_draws(self, E: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Host RNG for one fused block: ``(factors, picks)``, both (E,) f32.
-
-        One ``sample_horizon`` + one uniform vector call per block — the
-        horizon batcher's draw order, so the fused stream is a
-        different-but-deterministic realization exactly like ``horizon=K``
-        (see the module docstring); determinism per (seed, block size) is
-        pinned by tests/test_fused_stream.py.
-        """
-        factors = np.asarray(self.sampler.sample_horizon(E), dtype=np.float32)
-        picks = self._rng.random(E).astype(np.float32)
-        return factors, picks
-
-    def fused_initial_times(self) -> np.ndarray:
-        """(n,) f32 first completion times (same draw as the heap init)."""
-        return np.asarray(self.sampler.sample_batch(np.arange(self.n)),
-                          dtype=np.float32)
 
     def _events_exact(self) -> Iterator[ScheduleEvent]:
         """The canonical stream: RNG draws happen per event, in event order,

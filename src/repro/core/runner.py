@@ -47,7 +47,10 @@ ladder and the gradient's FLOPs per byte of a worker's rows):
   fetched once when the run ends.
 
 The legacy interpreter is kept behind ``mode="per_event"`` for equivalence
-testing (tests/test_event_stream.py) and as the reference semantics.
+testing (tests/test_event_stream.py) and as the reference semantics.  All
+four modes (``auto``, ``scan``, ``sparse_scan``, ``per_event``) consume the
+scheduler's exact event stream, so their runs match ``per_event``
+event for event.
 """
 from __future__ import annotations
 
@@ -66,8 +69,8 @@ from repro.core.scheduler import (BucketedSparseEventBatch, EventBatch,
                                   merge_event_groups)
 from repro.obs import RunLogger, init_metrics, metrics_summary
 from repro.obs.critical_path import straggler_tax
-from repro.obs.metrics import dense_metrics_update, fused_metrics_fold
-from repro.obs.trace import TraceRecorder, drain_fused_payload
+from repro.obs.metrics import dense_metrics_update
+from repro.obs.trace import TraceRecorder
 from repro.utils.tree import tree_size, tree_stack
 
 
@@ -177,13 +180,12 @@ class RunCounters:
     ``run()`` attaches each run's share (:meth:`since`) to its
     ``runner:run`` profiler span as span metadata, so a profiler trace
     carries the counts beside the spans.  ``per_event`` mode dispatches no
-    block programs (``blocks``/``rows`` stay 0); ``fused`` mode draws its
-    pairs on the device, so its ``active`` lanes stay uncounted.
+    block programs (``blocks``/``rows`` stay 0).
 
     ``grad_evaluated`` counts the gradient lanes the programs evaluate,
     masked and padding lanes included: n a dense scan row (padded rows
     too) or ``per_event`` event, lanes × steps of each sparse block (its
-    padded rows skip the update), 2 a ``fused`` event.
+    padded rows skip the update).
     """
     events: int = 0       # events consumed
     blocks: int = 0       # block programs dispatched
@@ -237,7 +239,7 @@ class DecentralizedTrainer:
                                             # scheduler's lane ladder and
                                             # the gradient's cost) |
                                             # "scan" | "sparse_scan" |
-                                            # "per_event" | "fused"
+                                            # "per_event"
         block_size: int = 32,               # events per compiled scan call
         batch_pool: Optional[int] = None,   # pre-drawn samples per worker
                                             # (scan mode; None = auto from the
@@ -252,11 +254,6 @@ class DecentralizedTrainer:
                                             # step (None = auto per bucket,
                                             # min(64, n) lanes/step; 1
                                             # disables)
-        native_generation: bool = True,     # sparse path: schedulers with an
-                                            # array-native generator fill the
-                                            # packed chunks directly (bit-
-                                            # identical; False forces the
-                                            # per-event object adapter)
         telemetry: bool = False,            # device-resident per-worker
                                             # counters (repro.obs): drained
                                             # once per run into
@@ -266,8 +263,7 @@ class DecentralizedTrainer:
                                             # wait-blame summary in
                                             # RunResult.trace, full Trace
                                             # in trainer.last_trace —
-                                            # host-side recording, one
-                                            # device fetch max (fused)
+                                            # host-side recording
         run_log: Optional[Union[str, object]] = None,
                                             # JSONL structured run log: a
                                             # path, a file-like object, or
@@ -277,19 +273,13 @@ class DecentralizedTrainer:
                                             # d2h transfer guard (None = the
                                             # REPRO_SANITIZE env flag)
     ):
-        if mode not in ("scan", "sparse_scan", "per_event", "auto", "fused"):
+        if mode not in ("scan", "sparse_scan", "per_event", "auto"):
             raise ValueError(
-                "mode must be 'scan', 'sparse_scan', 'per_event', 'auto' "
-                f"or 'fused', got {mode!r}")
+                "mode must be 'scan', 'sparse_scan', 'per_event' or 'auto', "
+                f"got {mode!r}")
         self.dtype = jnp.dtype(dtype)
         if not jnp.issubdtype(self.dtype, jnp.floating):
             raise ValueError(f"dtype policy must be a float dtype, got {dtype!r}")
-        if mode == "fused" and not (hasattr(scheduler, "fused_spec")
-                                    and scheduler.fused_supported()):
-            raise ValueError(
-                "mode='fused' needs a single-edge scheduler (ad_psgd/agp) "
-                "whose time model has iid completion-time factors "
-                f"(TimeModel.iid_horizon); got {scheduler.name!r}")
         if mode == "sparse_scan" and scheduler.global_events:
             # Barrier streams (sync DSGD) touch all n workers every event:
             # the gather-compute-scatter path would gather everything anyway,
@@ -306,7 +296,6 @@ class DecentralizedTrainer:
         self.block_size = max(1, block_size)
         self.batch_pool = batch_pool if batch_pool is None else max(1, batch_pool)
         self.events_per_step = events_per_step
-        self.native_generation = native_generation
         self.telemetry = bool(telemetry)
         self.trace = bool(trace)
         if sanitize is None:
@@ -350,17 +339,12 @@ class DecentralizedTrainer:
         self._draw_count = np.zeros(self.n, dtype=np.int64)
         self._scan = None           # block-compiled jitted update (dense)
         self._sparse = None         # block-compiled jitted update (active-set)
-        self._fused = None          # generate-and-consume block (fused mode)
-        self._fused_clock = None    # (times, lock_free) device event-process carry
         self._pools = None          # (n, batch_pool, ...) on-device sample pools
         self._ptr = None            # (n,) int32 restart counters
         self._eval_accum = None     # jitted eval → device-buffer accumulator
         self._metrics = None        # MetricsCarry device accumulators
         self._metrics_step = None   # per-event jitted dense metrics update
         self._bucket_occ = None     # host per-rung occupancy aggregation
-        self._fused_payload = None  # per-block (t_ev, i, p, t_raw) device
-                                    #   streams, folded once at drain
-        self._fused_fold = None     # jitted fused_metrics_fold
         self._trace = None          # TraceRecorder (host-side buffers)
         self.last_trace = None      # finalized Trace of the latest run
         self.counters = RunCounters()
@@ -766,18 +750,6 @@ class DecentralizedTrainer:
         if not self.telemetry or self._metrics is None:
             return None
         with _span("runner:drain"):
-            if self._fused_payload:
-                # fold the whole fused run's streamed event identities in
-                # one compiled call (event indices restart at 0 with the
-                # per-run counter reset, so k0 = 0)
-                t_ev, i_seq, p_seq, t_raw = (
-                    jnp.concatenate(xs) if len(xs) > 1 else xs[0]
-                    for xs in zip(*self._fused_payload))
-                self._metrics = self._fused_fold(
-                    self._metrics, i_seq, p_seq, t_raw, t_ev,
-                    int(self.scheduler.fused_spec()["copies_pair"]),
-                    jnp.int32(0))
-                self._fused_payload = []
             summary = metrics_summary(
                 self._metrics, t_end,
                 n_minus_1_bound=self.scheduler.name == "dsgd_aau")
@@ -801,22 +773,9 @@ class DecentralizedTrainer:
         return summary
 
     def _trace_summary(self) -> Optional[Dict]:
-        """Finalize the recorded identity stream; one device fetch max.
-
-        Host modes recorded everything host-side already; a fused run's
-        buffered device blocks are fetched here with a single explicit
-        ``jax.device_get`` (``drain_fused_payload``).  Runs *before* the
-        telemetry drain in every finish path — ``_telemetry_summary``
-        consumes and clears ``_fused_payload``.
-        """
+        """Finalize the identity stream, recorded host-side as it ran."""
         if not self.trace or self._trace is None:
             return None
-        if self._fused_payload:
-            with _span("runner:drain"):
-                host = drain_fused_payload(self._fused_payload)
-            self._trace.record_fused(
-                *host,
-                copies_pair=int(self.scheduler.fused_spec()["copies_pair"]))
         tr = self._trace.finalize(algorithm=self.scheduler.name,
                                   mode=self.mode)
         self.last_trace = tr
@@ -835,38 +794,6 @@ class DecentralizedTrainer:
         needs more — pass ``batch_pool`` explicitly to pin both.
         """
         n = self.n
-        if self.mode == "fused":
-            self._ensure_fused()
-            # The block donates its carry: clone the state, advance the
-            # clones through one full-length block of zero-factor /
-            # zero-pick draws (η is traced data) and discard them.  No
-            # scheduler RNG is consumed, so the run's realization is
-            # untouched.
-            E = self.block_size
-            zeros = jnp.zeros((E,), dtype=jnp.float32)
-            clones = (jax.tree.map(jnp.array, self.W),
-                      jax.tree.map(jnp.array, self.S),
-                      jnp.array(self.y), jnp.array(self._ptr))
-            clock = (jnp.ones((n,), dtype=jnp.float32), jnp.float32(0.0))
-            carry, ys = self._fused(
-                *clones, self._pools, *clock,
-                jnp.int32(0), zeros, zeros, zeros,
-            )
-            # warmup's streamed payload is discarded (telemetry/trace widen
-            # the scan outputs; the block signature is otherwise identical)
-            t_seq = ys[0] if (self.telemetry or self.trace) else ys
-            carry[2].block_until_ready()
-            self._warm_eval()
-            # Also warm the per-eval recording ops (row build + history
-            # scatter + buffer growth): they are tiny eager dispatches, but
-            # their first-call compiles sum to ~0.25 s — 30× a whole
-            # steady-state block at N=256.  Scratch buffer only; state and
-            # scheduler RNG are untouched.
-            buf = self._fused_record(
-                jnp.zeros((2, 4), dtype=jnp.float32), 0, t_seq[-1],
-                jnp.int32(0))
-            jnp.concatenate([buf, jnp.zeros_like(buf)]).block_until_ready()
-            return
         if self.mode == "sparse_scan":
             self._ensure_sparse()
             buckets = self.scheduler.active_buckets()
@@ -929,8 +856,6 @@ class DecentralizedTrainer:
                 # previous run would alias as negative staleness
                 self._metrics = self._init_metrics(self.n)
                 self._bucket_occ = {}
-            if self.telemetry or self.trace:
-                self._fused_payload = []
             if self.trace:
                 self._trace = TraceRecorder(self.n)
             self._log.log("run_start", algorithm=self.scheduler.name, n=self.n,
@@ -938,15 +863,14 @@ class DecentralizedTrainer:
                           max_time=max_time, eval_every=eval_every,
                           dtype=str(self.dtype), telemetry=self.telemetry,
                           trace=self.trace)
-            if self.mode == "fused" or getattr(self.scheduler, "horizon", None):
+            if getattr(self.scheduler, "horizon", None):
                 self._log.warn_once(
                     "rng_order",
                     "event stream is a different-but-deterministic RNG-order "
-                    "realization (horizon batching / fused generation): "
-                    "distributionally identical to the exact per-event stream, "
-                    "not bit-identical to it.", warn=False)
-            drive = {"fused": self._run_fused,
-                     "sparse_scan": self._run_sparse_stream,
+                    "realization (horizon batching): distributionally "
+                    "identical to the exact per-event stream, not "
+                    "bit-identical to it.", warn=False)
+            drive = {"sparse_scan": self._run_sparse_stream,
                      "scan": self._run_scan}.get(self.mode, self._run_per_event)
             before = dataclasses.replace(self.counters)
             with self._maybe_sanitized():
@@ -1131,8 +1055,7 @@ class DecentralizedTrainer:
         k = -1
         rounds = 0
         with _span("runner:gen"):    # a new event process per run
-            stream = self.scheduler.packed_stream(
-                native=self.native_generation)
+            stream = self.scheduler.packed_stream()
         exhausted = False
         while not exhausted:
             until_eval = eval_every - rounds % eval_every
@@ -1186,154 +1109,6 @@ class DecentralizedTrainer:
         self._warn_pool_wrap(rounds)
         return self._finish_scan(eval_buf, meta, k, t, comm, rounds,
                                  active_sizes)
-
-    # -- fused mode --------------------------------------------------------
-    def _ensure_fused(self, max_events: Optional[int] = None):
-        if self._fused is None:
-            from repro.core.fused import build_fused_pair_scan
-            self._log.log("compile", key="fused", telemetry=self.telemetry,
-                          trace=self.trace)
-            # trace reuses telemetry's widened scan outputs — the block
-            # streams the identity tuple either way, so trace=True adds
-            # zero device work beyond what telemetry already pays
-            self._fused = build_fused_pair_scan(
-                self.loss_fn, self.scheduler.fused_spec(),
-                use_kernel=self.use_kernel,
-                telemetry=self.telemetry or self.trace)
-            # Same aliasing hazard as _ensure_sparse: the fused block
-            # donates both W and S.
-            if any(w is s for w, s in zip(jax.tree.leaves(self.W),
-                                          jax.tree.leaves(self.S))):
-                self.S = jax.tree.map(jnp.array, self.S)
-            if self.telemetry:
-                self._fused_fold = jax.jit(fused_metrics_fold,
-                                           static_argnums=(5,))
-        self._ensure_metrics()
-        self._ensure_pools(max_events)
-
-    def _run_fused(self, max_events, max_time, eval_every) -> RunResult:
-        """Drive the generate-and-consume block (``mode="fused"``).
-
-        Per block the host's only work is two vectorized RNG draws; the
-        event process itself (who fires, when, with whom) lives in the
-        compiled scan's carry.  The virtual clock is device-resident too,
-        so runs are bounded by ``max_events`` only.
-        """
-        if not max_events:
-            raise ValueError(
-                "mode='fused' runs are bounded by max_events; max_time is "
-                "unsupported (the virtual clock lives on device — bounding "
-                "by it would force a host sync per block)")
-        if max_time is not None:
-            raise ValueError("mode='fused' does not support max_time")
-        sched = self.scheduler
-        self._ensure_fused(max_events)
-        self._ensure_eval_accum()
-        copies_pair = int(sched.fused_spec()["copies_pair"])
-        if self._fused_clock is None:
-            self._fused_clock = (
-                jnp.asarray(sched.fused_initial_times(), dtype=jnp.float32),
-                jnp.float32(0.0))
-        times, lock_free = self._fused_clock
-        comm_dev = jnp.int32(0)
-        blk = max(1, min(self.block_size, eval_every, max_events))
-        # Eval rows carry [loss, metric, t_last, comm] — the virtual clock
-        # and the copy counter stay on device; everything is fetched once
-        # at the end.  The buffer starts at the same fixed shape warmup()
-        # precompiled the record scatter for, and doubles on demand
-        # (log₂(evals) growth compiles on the first run, none after).
-        eval_buf = jnp.zeros((2, 4), dtype=jnp.float32)
-        meta: List[Tuple[int, int]] = []  # (k, rounds_at_eval)
-        rounds = 0
-        while rounds < max_events:
-            until_eval = eval_every - rounds % eval_every
-            E = min(blk, until_eval, max_events - rounds)
-            with _span("runner:gen"):
-                factors, picks = sched.fused_draws(E)
-            with _span("runner:pack"):
-                # f32 cast on host: jnp.asarray of an f64 array would insert
-                # a convert_element_type op (a first-run compile); a
-                # same-dtype asarray is a pure device put
-                etas = np.asarray(self._etas_for(E, E, rounds),
-                                  dtype=np.float32)
-                xs = (jnp.asarray(factors, dtype=jnp.float32),
-                      jnp.asarray(picks, dtype=jnp.float32),
-                      jnp.asarray(etas, dtype=jnp.float32))
-            self._log.log("block_dispatch", mode="fused", events=E,
-                          rounds=rounds)
-            # one finisher per event: its lane is the gradient and the
-            # restart lane; the block evaluates both lanes of the pair
-            self.counters.add(events=E, blocks=1, rows=E, grad=E,
-                              restarts=E, grad_evaluated=2 * E)
-            with _span("dispatch:fused"):
-                (self.W, self.S, self.y, self._ptr, times, lock_free,
-                 comm_dev), ys = self._fused(
-                    self.W, self.S, self.y, self._ptr, self._pools,
-                    times, lock_free, comm_dev, *xs)
-            if self.telemetry or self.trace:
-                # buffer the block's (t_ev, i, p, t_raw) event stream on
-                # device — consumed once at drain (fused_metrics_fold /
-                # drain_fused_payload), so telemetry and trace add no
-                # in-loop work beyond the scan outputs
-                self._fused_payload.append(ys)
-                t_seq = ys[0]
-            else:
-                t_seq = ys
-            rounds += E
-            if rounds % eval_every == 0 or rounds >= max_events:
-                eval_buf = self._fused_record(
-                    eval_buf, len(meta), t_seq[-1], comm_dev)
-                meta.append((rounds - 1, rounds))
-        self._fused_clock = (times, lock_free)
-        self._warn_pool_wrap(rounds)
-        # one fetch; sliced on host (a device-side [:k] would compile a
-        # slice executable on the first run)
-        with _span("runner:drain"):
-            vals = np.asarray(jax.device_get(eval_buf))[:len(meta)]
-        # comm is exact through f32 up to 2^24 copies; pair-event counts
-        # (comm deltas / copies-per-pair) back out the mean active-set
-        # size — 2 lanes per pair event, 1 per isolated-worker event.
-        history = []
-        prev_comm = 0
-        prev_rounds = 0
-        for i, (mk, mr) in enumerate(meta):
-            loss, metric, tt, commf = (float(v) for v in vals[i])
-            comm_i = int(round(commf))
-            E_i = mr - prev_rounds
-            pairs = ((comm_i - prev_comm) // copies_pair
-                     if copies_pair else E_i)
-            history.append(HistoryPoint(
-                k=mk, time=tt, loss=loss, metric=metric,
-                comm_param_copies=comm_i,
-                n_active_mean=(E_i + min(pairs, E_i)) / max(E_i, 1)))
-            prev_comm, prev_rounds = comm_i, mr
-        t_end = history[-1].time
-        trc = self._trace_summary()   # before telemetry: it clears payload
-        tel = self._telemetry_summary(t_end)
-        self._log.log("run_end", rounds=rounds, t=t_end,
-                      comm=history[-1].comm_param_copies)
-        return RunResult(
-            algorithm=sched.name, history=history,
-            final_loss=history[-1].loss, final_metric=history[-1].metric,
-            total_events=rounds, total_time=t_end,
-            total_comm_copies=history[-1].comm_param_copies,
-            param_count=self.param_count,
-            bytes_per_scalar=self.dtype.itemsize,
-            telemetry=tel, trace=trc,
-        )
-
-    def _fused_record(self, eval_buf: jax.Array, i: int, t_last: jax.Array,
-                      comm_dev: jax.Array) -> jax.Array:
-        """Append one fused-mode history row ([loss, metric, t, comm]) —
-        all eager device ops, no host sync; warmup() precompiles them."""
-        with _span("runner:eval"):
-            row = jnp.concatenate([
-                self._eval_accum(self.W, self.y, self.eval_batch),
-                jnp.stack([t_last, comm_dev.astype(jnp.float32)])])
-            if i == eval_buf.shape[0]:
-                eval_buf = jnp.concatenate([eval_buf,
-                                            jnp.zeros_like(eval_buf)])
-            return eval_buf.at[jnp.asarray(i)].set(row)
 
     # -- on-device eval history -------------------------------------------
     def _ensure_eval_accum(self):

@@ -31,10 +31,9 @@ affordable (a single-edge event carries a 2×2 submatrix instead of an
 n×n one).  Both ``from_events`` packers are vectorized numpy batch
 scatters (no per-event Python loop over ``np.ix_`` rectangles), so packing
 keeps up with the sparse-native generators.  The runner packs blocks
-itself via the ``from_events`` classmethods (its chunking snaps to the
-eval grid and the run bounds); :meth:`Scheduler.event_batches` /
-:meth:`Scheduler.sparse_event_batches` are the standalone fixed-size
-packing APIs for benchmarks and diagnostics.
+itself (its chunking snaps to the eval grid and the run bounds): dense
+blocks via ``EventBatch.from_events``, sparse ones pulled from
+:meth:`Scheduler.packed_stream`.
 
 Staleness semantics: a worker's gradient is evaluated at the parameter
 *snapshot it held when it started computing* (``restart_workers`` marks where
@@ -1097,7 +1096,7 @@ class Scheduler:
         ``ScheduleEvent`` objects) return their stream here.  The packed
         arrays must be *bit-identical* to the adapter path's — same RNG
         consumption order, same float casts — which
-        tests/test_fused_stream.py pins chunk-by-chunk for every scheduler.
+        tests/test_packed_stream.py pins chunk-by-chunk for every scheduler.
         """
         return None
 
@@ -1114,97 +1113,6 @@ class Scheduler:
             if stream is not None:
                 return stream
         return PackedEventStream(self)
-
-    def event_batches(self, block_size: int) -> Iterator[EventBatch]:
-        """Pack consecutive events into EventBatches of ``block_size``.
-
-        A finite event stream ends with one trailing partial batch (the
-        built-in schedulers stream forever, but subclasses may not).
-        """
-        if block_size < 1:
-            raise ValueError("block_size must be >= 1")
-        bound = self.edge_bound()
-        buf: List[ScheduleEvent] = []
-        for ev in self.events():
-            buf.append(ev)
-            if len(buf) == block_size:
-                yield EventBatch.from_events(buf, edge_bound=bound)
-                buf = []
-        if buf:
-            yield EventBatch.from_events(buf, edge_bound=bound)
-
-    def sparse_event_batches(self, block_size: int,
-                             native: bool = True) -> Iterator[SparseEventBatch]:
-        """Pack consecutive events into active-set SparseEventBatches.
-
-        With ``native=True`` (default) single-rung schedulers that carry an
-        array-native generator fill the packed arrays directly — no
-        per-event ``ScheduleEvent`` objects — producing bit-identical
-        batches to the object path (``native=False``).
-        """
-        if block_size < 1:
-            raise ValueError("block_size must be >= 1")
-        if native and len(self.active_buckets()) == 1:
-            stream = self._native_packed_stream()
-            if stream is not None and not stream.bucketed:
-                while True:
-                    chunk = stream.next_chunk(block_size)
-                    if chunk is None:
-                        return
-                    yield chunk
-                    if chunk.E < block_size:
-                        return
-        abound = self.active_bound()
-        ebound = self.edge_bound()
-        buf: List[ScheduleEvent] = []
-        for ev in self.events():
-            buf.append(ev)
-            if len(buf) == block_size:
-                yield SparseEventBatch.from_events(
-                    buf, active_bound=abound, edge_bound=ebound)
-                buf = []
-        if buf:
-            yield SparseEventBatch.from_events(
-                buf, active_bound=abound, edge_bound=ebound)
-
-    def bucketed_sparse_event_batches(
-            self, block_size: int,
-            native: bool = True) -> Iterator[BucketedSparseEventBatch]:
-        """Pack consecutive events into bucketed lane-width batches.
-
-        ``native=True`` (default) takes the scheduler's array-native
-        generator when it produces bucketed chunks (multi-rung ladders).
-        """
-        if block_size < 1:
-            raise ValueError("block_size must be >= 1")
-        if native and len(self.active_buckets()) > 1:
-            stream = self._native_packed_stream()
-            if stream is not None and stream.bucketed:
-                while True:
-                    chunk = stream.next_chunk(block_size)
-                    if chunk is None:
-                        return
-                    yield chunk
-                    if chunk.E < block_size:
-                        return
-        buckets = self.active_buckets()
-        ebound = self.edge_bound()
-        buf: List[ScheduleEvent] = []
-        for ev in self.events():
-            buf.append(ev)
-            if len(buf) == block_size:
-                yield BucketedSparseEventBatch.from_events(
-                    buf, buckets=buckets, edge_bound=ebound)
-                buf = []
-        if buf:
-            yield BucketedSparseEventBatch.from_events(
-                buf, buckets=buckets, edge_bound=ebound)
-
-    # -- shared helpers ---------------------------------------------------
-    def _mask(self, workers) -> np.ndarray:
-        m = np.zeros(self.n, dtype=bool)
-        m[list(workers)] = True
-        return m
 
 
 class AAUScheduler(Scheduler):

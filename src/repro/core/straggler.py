@@ -40,12 +40,6 @@ class StragglerModel:
 class TimeSampler:
     """Stateful sampler: ``sample(worker) -> duration`` of one local gradient."""
 
-    #: Duration *factors* (jitter × straggler slowdown) are iid across
-    #: workers and draws — per-worker structure lives entirely in ``base``
-    #: — so a pre-drawn flat factor stream may be assigned to workers in
-    #: any order (the fused on-device generator's gate, core/fused.py).
-    iid_horizon = True
-
     #: rng-order sampler surface (repro.check): duration draws happen in
     #: these methods only; ``__init__`` pins the heterogeneity draw.
     rng_methods = ("sample", "sample_batch", "sample_horizon")

@@ -6,9 +6,8 @@ gossip participation, busy/idle virtual time, and dtype-aware
 communication accounting — implemented as a :class:`MetricsCarry` of
 device accumulator arrays that rides the ``(W, S, y, ptr)`` scan carries
 of every execution mode and is drained to host once per run (never per
-event: after PR 7 fused generation and consumption into one compiled
-scan, any per-event host sync would reintroduce the dispatch overhead
-PRs 3–7 removed).
+event: a per-event host sync would reintroduce the dispatch overhead the
+block-compiled scans removed).
 
 On top of the aggregate counters, the tracing layer
 (:mod:`repro.obs.trace` + :mod:`repro.obs.critical_path`) buffers the
@@ -28,17 +27,14 @@ compiled blocks' phase scopes (``grad``, ``mix``, ``sparse_gather``,
 from repro.obs.critical_path import (attribute_wait, critical_path,
                                      straggler_tax)
 from repro.obs.metrics import (MetricsCarry, block_metrics_update,
-                               dense_metrics_update, fused_metrics_fold,
-                               init_metrics, metrics_summary,
-                               sparse_metrics_update)
+                               dense_metrics_update, init_metrics,
+                               metrics_summary, sparse_metrics_update)
 from repro.obs.runlog import RunLogger
-from repro.obs.trace import (Trace, TraceRecorder, chrome_trace,
-                             drain_fused_payload)
+from repro.obs.trace import Trace, TraceRecorder, chrome_trace
 
 __all__ = [
     "MetricsCarry", "RunLogger", "Trace", "TraceRecorder",
     "attribute_wait", "block_metrics_update", "chrome_trace",
-    "critical_path", "dense_metrics_update", "drain_fused_payload",
-    "fused_metrics_fold", "init_metrics", "metrics_summary",
-    "sparse_metrics_update", "straggler_tax",
+    "critical_path", "dense_metrics_update", "init_metrics",
+    "metrics_summary", "sparse_metrics_update", "straggler_tax",
 ]

@@ -72,7 +72,7 @@ def attribute_wait(trace: Trace) -> Dict[str, np.ndarray]:
         }
     # One vectorized pass over the restart lanes (already in ascending
     # event order).  The attribution runs inside every traced run's drain:
-    # a per-event Python loop costs more than the fused block itself at
+    # a per-event Python loop costs more than the sparse block itself at
     # bench scale, which would break the < 1.10x overhead contract.
     ev = np.asarray(trace.lane_ev)[r]
     w = np.asarray(trace.lane_worker)[r].astype(np.int64)

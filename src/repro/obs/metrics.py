@@ -2,7 +2,7 @@
 
 :class:`MetricsCarry` is a NamedTuple of small device arrays that rides the
 ``(W, S, y, ptr)`` carry of every execution mode's scan (dense, sparse,
-bucketed, fused) and of the per-event interpreter's jitted step.  Updates
+bucketed) and of the per-event interpreter's jitted step.  Updates
 are **order-exact across representations**: every accumulator uses only
 operations whose result is independent of how the stream is chunked or
 merged —
@@ -20,10 +20,7 @@ merged —
 
 so the drained counters are **bit-identical** across ``per_event``,
 ``scan``, ``sparse_scan`` and bucketed dispatch of the same stream, which
-tests/test_telemetry.py pins.  (``fused`` is a different-but-deterministic
-RNG realization of the stream — see core/fused.py — so its counters are
-internally consistent and deterministic, not event-matched to the host
-generators'.)
+tests/test_telemetry.py pins.
 
 Staleness semantics: a worker's gradient is evaluated at the snapshot it
 took at its previous restart, so when worker ``w`` fires a gradient at
@@ -150,7 +147,7 @@ def sparse_metrics_update(M: MetricsCarry, workers: jax.Array,
                           ts: jax.Array, fin: jax.Array, ks: jax.Array,
                           copies: jax.Array) -> MetricsCarry:
     """One active-set scan step's telemetry (``sparse_scan`` / bucketed /
-    merged rows / ``fused``).
+    merged rows).
 
     workers: (A,) int32, −1-padded; P_sub: (A, A); gm/rm: (A,) per-lane
     bools; ts/fin: (A,) f32 per-lane event / raw-completion clocks (merged
@@ -211,10 +208,8 @@ def block_metrics_update(M: MetricsCarry, workers: jax.Array,
     with an exclusive ``lax.cummax`` prefix over the block, and every
     accumulator lands in a single flattened scatter per block, so
     telemetry cost is O(E·n) vectorized work amortized over E events.
-    This is the *generic* block fold (arbitrary lane payloads); it serves
-    as the tested bridge between the sequential per-event updates and
-    :func:`fused_metrics_fold`, the O(E) specialization the fused runner
-    actually drains through.
+    This is the *generic* block fold (arbitrary lane payloads), pinned
+    against the sequential per-event updates by tests/test_telemetry.py.
 
     workers: (E, A) int32, −1-padded; gm/rm/coupled: (E, A) bools (the
     caller derives ``coupled`` from its payload structure); ts: (E,) f32
@@ -283,80 +278,6 @@ def block_metrics_update(M: MetricsCarry, workers: jax.Array,
         stale_sum=stale_sum,
         stale_hist=M.stale_hist.at[_stale_bins(s).ravel()].add(gi.ravel()),
         comm_copies=M.comm_copies + jnp.sum(copies).astype(jnp.int32),
-    )
-
-
-def fused_metrics_fold(M: MetricsCarry, i_seq: jax.Array, p_seq: jax.Array,
-                       t_raw: jax.Array, t_ev: jax.Array,
-                       copies_pair: int, k0: jax.Array) -> MetricsCarry:
-    """Drain-time fold of a fused run's streamed event identities.
-
-    The fused event process has structure the generic block fold cannot
-    assume: every event has exactly **one** gradient = restart worker (the
-    finisher ``i_seq[e]``), the coupled set is ``{i, p}`` iff a partner
-    exists (``p_seq[e] >= 0``), the finisher's busy interval ends at its
-    raw completion ``t_raw`` (its idle is the lock wait ``t_ev − t_raw``)
-    and a pair event ships ``copies_pair`` copies.  That collapses every
-    accumulator to an O(E) scatter except the last-restart prefix, which
-    stays one (E, n) compare + ``lax.cummax``.  The fused scan therefore
-    only streams out ``(t_ev, i, p, t_raw)`` per event — no per-block
-    metrics work at all — and the runner calls this **once per run** over
-    the concatenated blocks, making telemetry's in-run cost just the three
-    extra scan outputs.
-
-    i_seq/p_seq: (E,) int32 finisher / partner (−1 when isolated);
-    t_raw/t_ev: (E,) f32 raw and lock-shifted event clocks; copies_pair:
-    static int; k0: scalar int32 index of the first event (the run's
-    event indices are ``k0 + arange(E)``).
-
-    Equivalent to rebuilding the 2-lane payloads and folding them through
-    :func:`block_metrics_update` (tests/test_telemetry.py pins this); the
-    same f32 caveat applies — busy/idle sums are deterministic but not
-    add-order-identical to the per-event fold.
-    """
-    n = M.grad_steps.shape[0]
-    E = i_seq.shape[0]
-    ks = k0 + jnp.arange(E, dtype=jnp.int32)
-    has = p_seq >= 0
-    # exclusive per-worker last-restart prefix: the finisher restarts at
-    # its own event, so the (E, n) one-hot is a single compare
-    hot_r = i_seq[:, None] == jnp.arange(n, dtype=jnp.int32)
-    rk = jnp.where(hot_r, ks[:, None], jnp.int32(-1))
-    cmax = jax.lax.cummax(rk, axis=0)
-    prefix = jnp.concatenate(
-        [jnp.full((1, n), -1, dtype=jnp.int32), cmax[:-1]])
-    in_run = prefix >= k0
-    pos = jnp.clip(prefix - k0, 0, E - 1)
-    eff_k = jnp.where(in_run, prefix, M.last_restart_k[None, :])
-    eff_t = jnp.where(in_run, t_ev[pos], M.last_restart_t[None, :])
-    lk = jnp.take_along_axis(eff_k, i_seq[:, None], axis=1)[:, 0]
-    lt = jnp.take_along_axis(eff_t, i_seq[:, None], axis=1)[:, 0]
-    s = ks - lk - 1                                     # every event fires
-    # both coupled lanes in one flattened scatter; isolated events route
-    # both slots to the dropped n bucket
-    midx = jnp.concatenate([jnp.where(has, i_seq, n),
-                            jnp.where(has, p_seq, n)])
-    mix_k = jnp.full((n + 1,), -1, dtype=jnp.int32).at[midx].max(
-        jnp.concatenate([ks, ks]))[:n]
-    fin_k = cmax[-1]
-    fin_in = fin_k >= k0
-    return MetricsCarry(
-        grad_steps=M.grad_steps.at[i_seq].add(1),
-        mix_count=M.mix_count.at[midx].add(1, mode="drop"),
-        last_restart_k=jnp.where(fin_in, fin_k, M.last_restart_k),
-        last_mix_t=jnp.where(mix_k >= k0,
-                             t_ev[jnp.clip(mix_k - k0, 0, E - 1)],
-                             M.last_mix_t),
-        last_restart_t=jnp.where(fin_in,
-                                 t_ev[jnp.clip(fin_k - k0, 0, E - 1)],
-                                 M.last_restart_t),
-        busy_t=M.busy_t.at[i_seq].add(t_raw - lt),
-        idle_t=M.idle_t.at[i_seq].add(t_ev - t_raw),
-        stale_max=jnp.maximum(M.stale_max, jnp.max(s)).astype(jnp.int32),
-        stale_sum=(M.stale_sum + jnp.sum(s)).astype(jnp.int32),
-        stale_hist=M.stale_hist.at[_stale_bins(s)].add(1),
-        comm_copies=M.comm_copies
-        + jnp.sum(jnp.where(has, copies_pair, 0)).astype(jnp.int32),
     )
 
 

@@ -1,7 +1,7 @@
 """Virtual-time tracing: per-worker timelines from the drained event stream.
 
 Every execution mode of :class:`~repro.core.runner.DecentralizedTrainer`
-already materializes, per event, the identity tuple the fused scan streams
+already materializes, per event, the event's identity tuple
 — *(event clock, participating workers, per-lane raw completion clocks,
 grad/restart lanes, gossip edges, copies sent)*.  :class:`TraceRecorder`
 buffers exactly that identity stream and normalizes it into a
@@ -18,13 +18,6 @@ Recording cost follows the drain-once discipline of the telemetry layer
   and zero host drains** — the recorder slices arrays that already exist.
   All four modes record the *pre-merge, pre-pad* stream, so their traces
   are bit-identical to the per-event reference (tests/test_trace.py).
-- ``fused`` keeps the whole event process on device; the runner buffers
-  each block's ``(t_ev, i, p, t_raw)`` scan outputs (the same payload
-  telemetry folds) and :func:`drain_fused_payload` fetches the
-  concatenation with **exactly one** explicit ``jax.device_get`` at run
-  end.  The fused realization is a different-but-deterministic RNG
-  realization of the stream (see core/fused.py), so its trace is
-  internally consistent rather than event-matched to the host modes'.
 
 The Chrome Trace Event Format exporter (:func:`chrome_trace`) renders the
 virtual-time track, loadable in Perfetto / ``chrome://tracing``: one
@@ -38,14 +31,13 @@ phase scopes put it in the JAX profiler's trace (docs/observability.md).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence
 
 import numpy as np
 
 __all__ = [
     "Trace",
     "TraceRecorder",
-    "drain_fused_payload",
     "chrome_trace",
 ]
 
@@ -100,9 +92,8 @@ class TraceRecorder:
     """Accumulates identity chunks; :meth:`finalize` concatenates once.
 
     The record methods mirror the runner's per-mode stream forms and are
-    all pure host work over arrays the driving loop already holds; the
-    only device interaction in the whole trace path is the caller's single
-    :func:`drain_fused_payload` fetch for ``mode="fused"``.
+    all pure host work over arrays the driving loop already holds: the
+    trace path makes no device transfer at all.
     """
 
     def __init__(self, n: int):
@@ -180,45 +171,6 @@ class TraceRecorder:
         else:
             self.record_sparse(chunk)
 
-    def record_fused(self, t_ev: np.ndarray, i_seq: np.ndarray,
-                     p_seq: np.ndarray, t_raw: np.ndarray,
-                     copies_pair: int) -> None:
-        """The fused run's drained identity stream (host arrays).
-
-        Lane rebuild convention (matches ``fused_metrics_fold``): every
-        event has one finisher ``i`` (grad = restart lane, completion at
-        ``t_raw``) and, when ``p >= 0``, a gossip partner whose own
-        computation is untouched — its lane is present (completion shown
-        at the commit clock) but fires neither gradient nor restart.
-        """
-        t_ev = np.asarray(t_ev, dtype=np.float64)
-        t_raw = np.asarray(t_raw, dtype=np.float64)
-        i = np.asarray(i_seq, dtype=np.int32)
-        p = np.asarray(p_seq, dtype=np.int32)
-        E = t_ev.shape[0]
-        has = p >= 0
-        lo = np.where(has, np.minimum(i, p), i).astype(np.int32)
-        hi = np.where(has, np.maximum(i, p), i).astype(np.int32)
-        w2 = np.stack([lo, hi], axis=1)                   # (E, 2) ascending
-        valid2 = np.stack([np.ones(E, dtype=bool), has], axis=1)
-        grad2 = (w2 == i[:, None]) & valid2
-        fin2 = np.where(grad2, t_raw[:, None], t_ev[:, None])
-        rows, cols = np.nonzero(valid2)
-        eidx = np.nonzero(has)[0]
-        self._chunks.append({
-            "times": t_ev,
-            "copies": np.where(has, int(copies_pair), 0).astype(np.int64),
-            "lane_ev": self._k + rows.astype(np.int64),
-            "lane_worker": w2[rows, cols],
-            "lane_fin": fin2[rows, cols],
-            "lane_grad": grad2[rows, cols],
-            "lane_restart": grad2[rows, cols],
-            "edge_ev": self._k + eidx.astype(np.int64),
-            "edge_src": lo[eidx],
-            "edge_dst": hi[eidx],
-        })
-        self._k += E
-
     # -- drain -------------------------------------------------------------
     def finalize(self, algorithm: str = "", mode: str = "") -> Trace:
         cat: Dict[str, np.ndarray] = {}
@@ -237,24 +189,6 @@ def _empty_like_key(key: str) -> np.ndarray:
     if key in ("lane_grad", "lane_restart"):
         return np.zeros(0, dtype=bool)
     return np.zeros(0, dtype=np.int32)
-
-
-def drain_fused_payload(payload: Sequence) -> Tuple[np.ndarray, ...]:
-    """Fetch the fused run's buffered identity blocks in ONE device read.
-
-    ``payload`` is the runner's per-block list of ``(t_ev, i, p, t_raw)``
-    device tuples; the blocks are concatenated on device and fetched with
-    a single explicit ``jax.device_get`` — the whole trace subsystem's
-    only device→host transfer (the host modes record from arrays the
-    driving loop already holds).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    t_ev, i_seq, p_seq, t_raw = (
-        jnp.concatenate(xs) if len(xs) > 1 else xs[0]
-        for xs in zip(*payload))
-    return jax.device_get((t_ev, i_seq, p_seq, t_raw))
 
 
 # -- Chrome Trace Event Format export ---------------------------------------

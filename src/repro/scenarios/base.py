@@ -155,20 +155,6 @@ class FactorSampler:
     def _factors_for(self, workers: np.ndarray) -> np.ndarray:
         return self._factors_iid(len(workers))
 
-    @property
-    def iid_horizon(self) -> bool:
-        """Whether factor draws are exchangeable across workers and events.
-
-        True exactly when the subclass kept the default ``_factors_for`` /
-        ``sample_horizon`` (pure ``_factors_iid`` scenarios): a pre-drawn
-        flat factor stream can then be assigned to workers in any order
-        without changing the process law — the gate for the fused on-device
-        generator (core/fused.py).  Worker/history-dependent overrides
-        (diurnal) report False and keep the host paths.
-        """
-        return (type(self)._factors_for is FactorSampler._factors_for
-                and type(self).sample_horizon is FactorSampler.sample_horizon)
-
     # -- TimeModel ---------------------------------------------------------
     def sample_batch(self, workers) -> np.ndarray:
         workers = np.asarray(workers, dtype=np.intp)

@@ -112,31 +112,6 @@ def smoke_xl_spec() -> ExperimentSpec:
     )
 
 
-def fused_smoke_spec() -> ExperimentSpec:
-    """CI tier for ``mode="fused"`` — the device-resident event generator.
-
-    The two single-edge gossip algorithms whose event processes admit a
-    pure-JAX generator (AD-PSGD, AGP) under an iid-horizon scenario, for a
-    few blocks: proves the fused generate-and-consume scan compiles, runs,
-    and keeps exact communication accounting end to end.  Event-bounded by
-    construction — fused runs keep the virtual clock on device.
-    """
-    return ExperimentSpec(
-        name="fused_smoke",
-        algorithms=("ad_psgd", "agp"),
-        reference=None,
-        scenarios=("paper_default",),
-        scales=(8,),
-        seeds=(0,),
-        mode="fused",
-        block_size=16,
-        max_events=48,
-        max_time=None,
-        eval_every=24,
-        target_loss=0.9,
-    )
-
-
 def trace_tables_spec() -> ExperimentSpec:
     """Recorded configuration behind ``BENCH_trace.json``.
 
@@ -169,7 +144,6 @@ PRESETS = {
     "paper_figures_xl": paper_figures_xl_spec,
     "smoke": smoke_spec,
     "smoke_xl": smoke_xl_spec,
-    "fused_smoke": fused_smoke_spec,
     "trace_tables": trace_tables_spec,
 }
 

@@ -78,13 +78,8 @@ class ExperimentSpec:
         for alg in self.algorithms + ((self.reference,) if self.reference else ()):
             if alg not in KNOWN_ALGS:
                 raise KeyError(f"unknown algorithm {alg!r}; have {KNOWN_ALGS}")
-        if self.mode not in ("scan", "sparse_scan", "per_event", "auto",
-                             "fused"):
+        if self.mode not in ("scan", "sparse_scan", "per_event", "auto"):
             raise ValueError(f"unknown mode {self.mode!r}")
-        if self.mode == "fused" and self.max_time is not None:
-            raise ValueError(
-                "mode='fused' keeps the virtual clock on device and is "
-                "bounded by max_events only; set max_time=None")
         if not (self.max_events or self.max_time):
             raise ValueError("spec needs max_events or max_time")
         if any(n < 2 for n in self.scales):

@@ -1,7 +1,7 @@
 """Runtime sanitizers: transfer guard, leak check, compile-count contract.
 
 The N=64 regression pins **zero implicit device→host transfers per
-compiled block** for the sparse_scan / bucketed / fused paths: the whole
+compiled block** for the sparse_scan / bucketed / dense paths: the whole
 driving loop runs under :func:`repro.check.runtime.sanitized`, whose
 host-conversion guard raises on any ``float()``/``np.asarray()``/
 ``.item()`` applied to a jax value outside an explicit
@@ -95,8 +95,8 @@ class TestHostConversionGuard:
 
 
 class TestZeroImplicitTransfersN64:
-    """The regression the ISSUE pins: sparse_scan / bucketed / fused at
-    N=64 complete a full run with zero implicit device→host transfers.
+    """sparse_scan / bucketed / dense scan at N=64 complete a full run
+    with zero implicit device→host transfers.
 
     The runs wrap in the transfer guard alone (``check_leaks=False``):
     tracing the N=64 scan under ``jax.checking_leaks`` costs minutes, and
@@ -106,8 +106,8 @@ class TestZeroImplicitTransfersN64:
     @pytest.mark.parametrize("alg,mode", [
         ("ad_psgd", "sparse_scan"),            # single-rung sparse
         ("dsgd_aau", "sparse_scan"),           # bucketed (16, 64)
-        ("ad_psgd", "fused"),                  # generate-and-consume
-    ], ids=["sparse_scan", "bucketed", "fused"])
+        ("dsgd_sync", "scan"),                 # dense barrier scan
+    ], ids=["sparse_scan", "bucketed", "dense"])
     def test_run_has_zero_implicit_transfers(self, alg, mode):
         tr = _trainer(alg, mode, block_size=16)
         with sanitized(check_leaks=False):

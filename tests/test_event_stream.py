@@ -72,7 +72,8 @@ class TestEventBatchPacking:
 
     def test_event_batches_api(self):
         sched = _sched("ad_psgd")
-        batches = list(itertools.islice(sched.event_batches(5), 3))
+        stream = sched.packed_stream(native=False)
+        batches = [stream.next_chunk(5) for _ in range(3)]
         assert [b.E for b in batches] == [5, 5, 5]
         assert batches[1].k0 == 5  # consecutive packing
         # AD-PSGD's compact-edge form is one edge per event, not O(n²)
@@ -115,7 +116,7 @@ class TestScanEquivalence:
     """Same scheduler seed ⇒ the compiled scan path replays the per-event
     trainer exactly (fp32): parameters, snapshots, push-sum weights, history."""
 
-    @pytest.mark.parametrize("alg", ["dsgd_aau", "ad_psgd", "agp"])
+    @pytest.mark.parametrize("alg", ["dsgd_aau", "ad_psgd", "agp", "prague"])
     def test_matches_per_event(self, alg):
         ref = _trainer(alg, "per_event")
         res_ref = ref.run(max_events=40, eval_every=10)

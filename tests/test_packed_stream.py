@@ -1,7 +1,5 @@
-"""Device-resident event streaming: packed-native generation, event-blocked
-merged dispatch, and the fused on-device generator (``mode="fused"``).
-
-Three equivalence tiers, matching the three tentpole stages:
+"""The packed event stream: native generation, merged dispatch, the mode
+choice, and the exact pair stream's accounting.
 
 - the array-native packed generators (``packed_stream(native=True)``) must
   be **bit-identical** to the object-path adapter, chunk by chunk, for every
@@ -10,10 +8,10 @@ Three equivalence tiers, matching the three tentpole stages:
   **bit-exact** re-executions of the one-event-per-step sparse scan (the
   trajectory equivalence lives in tests/test_sparse_event_stream.py; here
   the merged-vs-unmerged runner paths are pinned against each other);
-- the fused generator is a *different-but-deterministic* realization
-  (horizon-order RNG), so it is pinned **distributionally**: exact event /
-  restart / comm accounting, event-rate agreement with the exact stream,
-  and per-(seed, block) determinism.
+- ``choose_mode`` picks the dense or the sparse scan from n, the lane
+  ladder and the gradient's cost;
+- the pair stream's event, restart and comm totals are exact, and
+  ``warmup()`` leaves it where it was.
 """
 import itertools
 
@@ -28,9 +26,8 @@ from repro.core.runner import (COMPUTE_BOUND_FLOPS_PER_BYTE,
                                DecentralizedTrainer, choose_mode)
 from repro.core.scheduler import (BucketedSparseEventBatch, PackedEventStream,
                                   SparseEventBatch, merge_event_groups)
-from repro.core.straggler import StragglerModel, TimeSampler
+from repro.core.straggler import StragglerModel
 from repro.data.synthetic import ClassificationData
-from repro.scenarios import get_scenario
 
 N = 16
 ALL_ALGS = ["dsgd_aau", "ad_psgd", "prague", "agp", "dsgd_sync"]
@@ -162,7 +159,7 @@ class TestMergeEventGroups:
             lane_off, np.broadcast_to(np.arange(batch.E)[:, None],
                                       (batch.E, batch.A)))
 
-    @pytest.mark.parametrize("alg", ["ad_psgd", "dsgd_aau"])
+    @pytest.mark.parametrize("alg", ["ad_psgd", "dsgd_aau", "agp", "prague"])
     def test_merged_dispatch_bit_exact_vs_one_event_per_step(self, alg):
         one = _trainer(alg, "sparse_scan", block_size=8, batch_pool=48,
                        events_per_step=1)
@@ -210,6 +207,11 @@ class TestChooseMode:
         assert tr.mode == "scan"  # N=16 sits below every crossover
         tr.run(max_events=16, eval_every=8)  # and the resolved mode runs
 
+    def test_fused_mode_is_refused(self):
+        # the four modes left all replay the exact event stream
+        with pytest.raises(ValueError, match="mode must be"):
+            _trainer("ad_psgd", "fused")
+
     def test_auto_picks_sparse_at_scale(self):
         g = topology.erdos_renyi(128, 0.1, seed=1)
         sm = StragglerModel(n=128, straggler_prob=0.1, slowdown=10.0, seed=0)
@@ -223,89 +225,42 @@ class TestChooseMode:
         assert tr.mode == "sparse_scan"
 
 
-class TestFusedGating:
-    def test_iid_horizon_flags(self):
-        assert TimeSampler.iid_horizon is True
-        for name in ("paper_default", "heavy_tail", "bimodal", "churn"):
-            assert get_scenario(name, n=N).make_sampler().iid_horizon, name
-        # diurnal factors depend on per-worker draw history: not exchangeable
-        assert not get_scenario("diurnal", n=N).make_sampler().iid_horizon
+class TestExactPairStream:
+    """AD-PSGD on the sparse scan: merged pairs, replayed exactly."""
 
-    def test_fused_supported_follows_sampler(self):
-        assert _sched("ad_psgd").fused_supported()
-        assert _sched("agp").fused_supported()
-        sched = _sched("ad_psgd",
-                       straggler=get_scenario("diurnal", n=N, seed=0))
-        assert not sched.fused_supported()
-
-    def test_fused_rejects_clique_schedulers(self):
-        with pytest.raises(ValueError, match="fused"):
-            _trainer("dsgd_aau", "fused")
-
-    def test_fused_rejects_history_dependent_sampler(self):
-        with pytest.raises(ValueError, match="iid"):
-            _trainer("ad_psgd", "fused",
-                     sched_kw=dict(straggler=get_scenario("diurnal", n=N)))
-
-
-class TestFusedStream:
     EVENTS = 96
 
-    def _run(self, alg="ad_psgd", seed=0, warmup=False, **kw):
-        tr = _trainer(alg, "fused", seed=seed, block_size=16, batch_pool=96,
-                      **kw)
+    def _run(self, warmup=False):
+        tr = _trainer("ad_psgd", "sparse_scan", block_size=16, batch_pool=96)
         if warmup:
             tr.warmup()
         res = tr.run(max_events=self.EVENTS, eval_every=24)
         return tr, res
-
-    @pytest.mark.parametrize("alg", ["ad_psgd", "agp"])
-    def test_deterministic_per_seed(self, alg):
-        t1, r1 = self._run(alg)
-        t2, r2 = self._run(alg)
-        np.testing.assert_array_equal(np.asarray(t1.W["w"]),
-                                      np.asarray(t2.W["w"]))
-        np.testing.assert_array_equal(np.asarray(t1.y), np.asarray(t2.y))
-        assert r1.total_time == r2.total_time
-        assert r1.total_comm_copies == r2.total_comm_copies
-        assert [p.loss for p in r1.history] == [p.loss for p in r2.history]
 
     def test_warmup_does_not_shift_the_stream(self):
         t1, r1 = self._run(warmup=False)
         t2, r2 = self._run(warmup=True)
         np.testing.assert_array_equal(np.asarray(t1.W["w"]),
                                       np.asarray(t2.W["w"]))
+        np.testing.assert_array_equal(np.asarray(t1.y), np.asarray(t2.y))
         assert r1.total_time == r2.total_time
+        assert [p.loss for p in r1.history] == [p.loss for p in r2.history]
 
     def test_exact_event_accounting(self):
         # erdos_renyi(16, 0.4, seed=3) is connected: every event is a pair
         # exchange, so comm and restart totals are exact, not statistical.
         sched = _sched("ad_psgd")
         assert all(len(nb) for nb in sched.graph.neighbor_lists)
-        copies_pair = int(sched.fused_spec()["copies_pair"])
+        copies_pair = next(sched.events()).param_copies_sent
+        assert copies_pair > 0
         tr, res = self._run()
         assert res.total_events == self.EVENTS
+        assert tr.counters.rows < self.EVENTS   # pairs merged per step
         assert res.total_comm_copies == self.EVENTS * copies_pair
         # one finisher restart per event
         assert int(np.asarray(tr._ptr).sum()) == self.EVENTS
-        # pair events: finisher + neighbor active
-        assert res.history[-1].n_active_mean == pytest.approx(2.0)
-
-    def test_distributional_match_with_exact_stream(self):
-        # The fused stream is a different realization of the same process:
-        # virtual-clock rate and per-worker activation spread must agree
-        # with the exact heap stream within sampling noise.
-        tr, res = self._run()
-        exact = _trainer("ad_psgd", "sparse_scan", block_size=16,
-                         batch_pool=96)
-        res_exact = exact.run(max_events=self.EVENTS, eval_every=24)
-        assert res.total_time == pytest.approx(res_exact.total_time, rel=0.5)
-        assert res.total_comm_copies == res_exact.total_comm_copies
-        ptr = np.asarray(tr._ptr)
-        # every worker keeps finishing work (96 events over 16 workers)
-        assert (ptr > 0).all()
-        assert ptr.max() <= 4 * self.EVENTS // N
-
-    def test_fused_loss_decreases(self):
-        _, res = self._run()
-        assert res.final_loss < res.history[0].loss
+        # pair events: finisher + neighbor active, the finisher's lane
+        # alone takes a gradient (n_active counts gradient lanes)
+        assert tr.counters.active == 2 * self.EVENTS
+        assert tr.counters.grad == self.EVENTS
+        assert res.history[-1].n_active_mean == 1.0

@@ -5,7 +5,7 @@ per-lane factor (``pool_lane_factor``) so that the TPU compiler cannot hoist
 a convert of the whole sample pool out of the scan
 (tests/test_tpu_compile.py checks the compiled program).  The factor is 1
 on every lane, so every sparse program — the sparse scan, its telemetry
-variant and the fused pair scan — must produce the same ``(W, S, y, ptr)``
+variant and the merged pair scan — must produce the same ``(W, S, y, ptr)``
 as with the plain ``pool[widx, ptr mod pool]`` gather: padded (-1) lanes,
 restart counters past the pool length (the wrap), f32 and bf16 state.
 """
@@ -138,12 +138,12 @@ def _trainer(alg, mode, dtype, telemetry):
         block_size=8, batch_pool=POOL, dtype=dtype, telemetry=telemetry)
 
 
-# (scheduler, mode, telemetry): the three sparse programs that run
-# sparse_event_update — the sparse scan, its telemetry variant and the
-# fused pair scan
+# (scheduler, mode, telemetry): the sparse programs that run
+# sparse_event_update — the bucketed sparse scan, its telemetry variant and
+# the single-rung pair scan, whose steps merge pairs
 SCANS = {"sparse": ("dsgd_aau", "sparse_scan", False),
          "telemetry": ("dsgd_aau", "sparse_scan", True),
-         "fused": ("ad_psgd", "fused", False)}
+         "pairs": ("ad_psgd", "sparse_scan", False)}
 
 
 @pytest.mark.filterwarnings("ignore:batch pool of")   # the wrap is wanted

@@ -218,10 +218,7 @@ def test_merge_budget_is_no_wider_than_n(n):
             assert K == int(np.clip(64 // A, 1, 16))
 
 
-def test_per_event_and_fused_count_their_lanes():
+def test_per_event_counts_its_lanes():
     tr = _trainer("dsgd_aau", "per_event")
     tr.run(max_events=6, eval_every=3)
     assert tr.counters.grad_evaluated == 6 * N
-    tr = _trainer("ad_psgd", "fused")
-    tr.run(max_events=8, eval_every=4)
-    assert tr.counters.grad_evaluated == 2 * 8

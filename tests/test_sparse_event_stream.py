@@ -80,7 +80,8 @@ class TestSparseEventBatchPacking:
         """AD-PSGD's sparse form is (E, 2) indices + (E, 2, 2) submatrices —
         the dense (E, n, n) stack is gone entirely."""
         sched = _sched("ad_psgd")
-        batches = list(itertools.islice(sched.sparse_event_batches(5), 2))
+        stream = sched.packed_stream(native=False)
+        batches = [stream.next_chunk(5) for _ in range(2)]
         assert [b.E for b in batches] == [5, 5]
         assert batches[1].k0 == 5
         assert batches[0].workers.shape == (5, 2)
@@ -89,7 +90,9 @@ class TestSparseEventBatchPacking:
 
     def test_sorted_active_sets_and_zero_padding(self):
         sched = _sched("dsgd_aau")
-        batch = next(sched.sparse_event_batches(8))
+        batch = SparseEventBatch.from_events(
+            list(itertools.islice(sched.events(), 8)),
+            active_bound=sched.active_bound(), edge_bound=sched.edge_bound())
         for e in range(batch.E):
             m = int(batch.n_workers[e])
             lanes = batch.workers[e]
@@ -143,7 +146,7 @@ class TestSparseScanEquivalence:
     """Same scheduler seed ⇒ sparse_scan ≡ scan ≡ per_event (fp32):
     parameters, snapshots, push-sum weights, and recorded history."""
 
-    @pytest.mark.parametrize("alg", ["dsgd_aau", "ad_psgd", "agp"])
+    @pytest.mark.parametrize("alg", ["dsgd_aau", "ad_psgd", "agp", "prague"])
     def test_matches_dense_scan_and_per_event(self, alg):
         per_event = _trainer(alg, "per_event")
         res_pe = per_event.run(max_events=40, eval_every=10)

@@ -7,8 +7,6 @@ The contract under test (see repro/obs/metrics.py):
 - the drained ``MetricsCarry`` is **bit-identical** across ``per_event``,
   ``scan`` and ``sparse_scan`` (incl. bucketed dispatch) of the same
   scheduler stream — every accumulator uses order-exact operations only;
-- the ``fused`` mode is a different-but-deterministic realization: its
-  counters are internally consistent and deterministic, not event-matched;
 - telemetry is a pure observer: trajectories are bit-identical with it on
   or off;
 - ``stale_max`` obeys the 2N−4 event-staleness bound induced by
@@ -32,7 +30,7 @@ from repro.core.straggler import StragglerModel
 from repro.data.synthetic import ClassificationData
 from repro.obs import RunLogger, init_metrics
 from repro.obs.metrics import (STALE_HIST_BINS, block_metrics_update,
-                               fused_metrics_fold, sparse_metrics_update)
+                               sparse_metrics_update)
 
 N = 16
 DATA = ClassificationData(n_workers=N, d=16, n_classes=4,
@@ -141,7 +139,8 @@ class TestTrajectoryUnchanged:
         ("dsgd_aau", "scan"),
         ("dsgd_aau", "sparse_scan"),
         ("dsgd_aau", "per_event"),
-        ("ad_psgd", "fused"),
+        ("ad_psgd", "sparse_scan"),
+        ("dsgd_sync", "scan"),
     ])
     def test_state_and_history_identical(self, alg, mode):
         results = {}
@@ -223,21 +222,23 @@ class TestStalenessBound:
         assert "staleness_bound" not in res.telemetry
 
 
-class TestFusedTelemetry:
-    """Fused mode: deterministic, internally consistent, block-fold
-    equals the sequential per-event fold on identical payloads."""
+class TestPairTelemetry:
+    """The merged pair stream: deterministic, internally consistent;
+    the block fold equals the sequential per-event fold on identical
+    payloads."""
 
     def test_deterministic_and_consistent(self):
         summ = []
         for _ in range(2):
-            tr = _trainer("ad_psgd", "fused")
+            tr = _trainer("ad_psgd", "sparse_scan")
             res = tr.run(max_events=96, eval_every=48)
             t = res.telemetry
+            # one gradient lane (the finisher's) per pair event
             assert sum(t["grad_steps"]) == res.total_events
             assert t["comm_copies"] == res.total_comm_copies
             assert sum(t["stale_hist"]) == sum(t["grad_steps"])
             summ.append(t)
-        assert summ[0] == summ[1], "fused telemetry not deterministic"
+        assert summ[0] == summ[1], "pair telemetry not deterministic"
 
     def test_block_fold_matches_sequential_fold(self):
         """block_metrics_update ≡ event-by-event sparse_metrics_update on
@@ -284,52 +285,6 @@ class TestFusedTelemetry:
                 jnp.asarray(ks[sl]), jnp.asarray(copies[sl]))
 
         a, b = jax.device_get(M_seq), jax.device_get(M_blk)
-        for f in a._fields:
-            av, bv = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
-            if av.dtype == np.float32 and f in ("busy_t", "idle_t"):
-                np.testing.assert_allclose(av, bv, rtol=1e-6, atol=1e-6,
-                                           err_msg=f)
-            else:
-                np.testing.assert_array_equal(av, bv, err_msg=f)
-
-    def test_fused_fold_matches_generic_block_fold(self):
-        """fused_metrics_fold (the O(E) drain-time specialization) ≡
-        block_metrics_update on the rebuilt 2-lane fused payloads."""
-        rng = np.random.default_rng(11)
-        n, E, k0, copies_pair = 7, 200, 0, 2
-        i_seq = rng.integers(0, n, E).astype(np.int32)
-        p_seq = np.where(rng.random(E) < 0.85,
-                         (i_seq + rng.integers(1, n, E)) % n,
-                         -1).astype(np.int32)
-        t_ev = np.cumsum(rng.random(E).astype(np.float32) * 0.1,
-                         dtype=np.float32)
-        t_raw = t_ev - rng.random(E).astype(np.float32) * 0.02
-        ks = (k0 + np.arange(E)).astype(np.int32)
-
-        # the rebuild the per-block path used: sorted pair, finisher lane
-        has = p_seq >= 0
-        workers = np.stack([np.where(has, np.minimum(i_seq, p_seq), i_seq),
-                            np.where(has, np.maximum(i_seq, p_seq), -1)],
-                           axis=1).astype(np.int32)
-        lanes = workers == i_seq[:, None]
-        coupled = has[:, None] & (workers >= 0)
-        fin = np.where(lanes, t_raw[:, None], t_ev[:, None])
-        copies = np.where(has, copies_pair, 0).astype(np.int32)
-        M_blk = block_metrics_update(
-            init_metrics(n), jnp.asarray(workers), jnp.asarray(lanes),
-            jnp.asarray(lanes), jnp.asarray(coupled), jnp.asarray(t_ev),
-            jnp.asarray(fin), jnp.asarray(ks), jnp.asarray(copies))
-
-        # the specialized fold, split across two drains' worth of carry
-        h = E // 3
-        M_fus = init_metrics(n)
-        for sl in (slice(None, h), slice(h, None)):
-            M_fus = fused_metrics_fold(
-                M_fus, jnp.asarray(i_seq[sl]), jnp.asarray(p_seq[sl]),
-                jnp.asarray(t_raw[sl]), jnp.asarray(t_ev[sl]),
-                copies_pair, jnp.int32(ks[sl][0]))
-
-        a, b = jax.device_get(M_blk), jax.device_get(M_fus)
         for f in a._fields:
             av, bv = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
             if av.dtype == np.float32 and f in ("busy_t", "idle_t"):
